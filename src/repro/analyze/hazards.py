@@ -263,7 +263,7 @@ def check_config(cfg, key=None, *, params: TpuParams | None = None,
         diags.append(Diagnostic(
             rule="ZS-S004", severity="error", where=where,
             message=f"revolving buffers need {fp} B of VMEM; the chip "
-                    f"has {params.vmem_bytes} B",
+                    f"grants a kernel {params.vmem_bytes} B",
             hint="shrink tiles or slots"))
     elif fp > params.vmem_bytes * _VMEM_FRACTION:
         diags.append(Diagnostic(
@@ -320,7 +320,7 @@ def _check_attention_config(cfg, key, *, params: TpuParams
         diags.append(Diagnostic(
             rule="ZS-S004", severity="error", where=where,
             message=f"flash working set needs {fp} B of VMEM; the chip "
-                    f"has {params.vmem_bytes} B",
+                    f"grants a kernel {params.vmem_bytes} B",
             hint="shrink bq/bkv"))
     elif fp > params.vmem_bytes * _VMEM_FRACTION:
         diags.append(Diagnostic(

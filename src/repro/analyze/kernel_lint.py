@@ -160,15 +160,16 @@ def extract_kernel_ir(eqn) -> KernelIR:
             index=i,
             kind="in" if i < gm.num_inputs else "out",
             blocked="<any>" not in aval_s.lower(),
-            block_shape=tuple(bmap.block_shape),
-            array_shape=tuple(bmap.array_shape_dtype.shape),
+            block_shape=tuple(getattr(d, "block_size", d)
+                              for d in bmap.block_shape),
+            array_shape=tuple(bmap.array_aval.shape),
             index_map=bmap.index_map_jaxpr))
     body = eqn.params["jaxpr"]
     consts = tuple(getattr(body, "consts", ()))
     body = _strip_closed(body)
-    name = eqn.params["name_and_src_info"].name
-    mosaic = (eqn.params.get("compiler_params") or {}).get("mosaic", {})
-    sem = mosaic.get("dimension_semantics")
+    name = eqn.params["name"]
+    mosaic = (eqn.params.get("compiler_params") or {}).get("mosaic_tpu")
+    sem = getattr(mosaic, "dimension_semantics", None)
     return KernelIR(
         name=name,
         grid=tuple(gm.grid),
@@ -289,7 +290,7 @@ class _Interp:
         env: dict = {}
 
         def read(atom):
-            if isinstance(atom, jax.core.Literal):
+            if isinstance(atom, jax.extend.core.Literal):
                 return self._lit(atom.val)
             return env[atom]
 
@@ -328,7 +329,7 @@ class _Interp:
                                        f"of range")
             return [self.grid[axis]]
 
-        if prim in ("pjit", "closed_call", "core_call", "custom_jvp_call",
+        if prim in ("jit", "closed_call", "core_call", "custom_jvp_call",
                     "custom_vjp_call"):
             sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
             if sub is None:

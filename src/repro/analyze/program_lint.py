@@ -28,10 +28,13 @@ fallback walks through unseen.
 
 from __future__ import annotations
 
+import contextlib
 import math
+import os
 from typing import Any, Callable
 
 import jax
+import jaxlib
 
 from repro.analyze.diagnostics import Diagnostic, Report
 
@@ -53,7 +56,11 @@ DEFAULT_ALLOW = (
     "in cross_entropy",          # loss: one-hot contraction, not a GEMM
 )
 
-_CALLBACK_PRIMS = ("infeed", "outfeed")
+_CALLBACK_PRIMS = ("infeed", "outfeed", "debug_print")
+# frames in jax, jaxlib and the contextlib wrappers around jax entry
+# points are never the user call site
+_SKIP = (*(os.path.dirname(m.__file__) + os.sep for m in (jax, jaxlib)),
+         contextlib.__file__)
 _INT_DTYPES = ("int8", "uint8", "int4", "uint4")
 
 
@@ -65,19 +72,18 @@ def _jaxpr_of(target) -> Any:
 
 
 def _source_of(eqn) -> str:
-    """Best-effort ``file:line in function`` for one equation."""
-    try:
-        from jax._src import source_info_util
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is None:
-            return "<unknown>"
-        line = getattr(frame, "start_line", None) or getattr(
-            frame, "line_num", "?")
-        fn = getattr(frame, "function_name", "")
-        src = f"{frame.file_name}:{line}"
-        return f"{src} in {fn}" if fn else src
-    except Exception:
-        return "<unknown>"
+    """``file:line in function`` of the innermost frame outside jax.
+
+    Reads the public traceback an equation carries
+    (``eqn.source_info.traceback``); its frames run innermost first, so
+    the first one outside jax, jaxlib and contextlib is the user call
+    site."""
+    tb = eqn.source_info.traceback
+    for frame in (tb.frames if tb is not None else ()):
+        if not frame.file_name.startswith(_SKIP):
+            return (f"{frame.file_name}:{frame.line_num} in "
+                    f"{frame.function_name}")
+    return "<unknown>"
 
 
 def _dot_flops(eqn) -> float:

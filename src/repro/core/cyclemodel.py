@@ -314,6 +314,15 @@ class SnitchClusterModel:
 # ----------------------------------------------------------------------
 # TPU pipeline model (the adaptation target)
 # ----------------------------------------------------------------------
+#: Scoped VMEM every Pallas kernel asks Mosaic for
+#: (``CompilerParams.vmem_limit_bytes``, see
+#: :func:`repro.kernels.meta.compiler_params`) out of v5e's 128 MiB.
+#: Mosaic's default grant is 16 MiB, which refuses tiles the tuner
+#: admits; the tuner budgets its revolving buffers against this same
+#: number, so every admitted tile compiles.
+VMEM_LIMIT_BYTES = 96 * 1024 * 1024
+
+
 @dataclasses.dataclass(frozen=True)
 class TpuParams:
     """TPU v5e-class single-chip constants (public figures)."""
@@ -324,7 +333,7 @@ class TpuParams:
                                       # spec; precision shifts the
                                       # roofline, PAPERS.md)
     hbm_bw: float = 819e9             # B/s
-    vmem_bytes: int = 128 * 1024 * 1024
+    vmem_bytes: int = VMEM_LIMIT_BYTES  # what a kernel may claim
     ici_bw: float = 50e9              # B/s per link
     # control overhead per tile step when the tile loop is *not* run by
     # the grid sequencer (host-driven dispatch / fori_loop bookkeeping).

@@ -198,8 +198,6 @@ def analyze_compiled(name: str, compiled, chips: int, *,
 
     hw = hw or HW()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
     try:
         hlo = compiled.as_text()
     except Exception:

@@ -36,8 +36,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-from repro.kernels.meta import kernel_name, register_family
+from repro.kernels.meta import (compiler_params, kernel_name,
+                                 register_family)
 
 # pipeline-managed double buffering (BlockSpec windows, no manual DMA);
 # only the kv streaming axis (last) must stay sequential — m/l/acc are
@@ -165,8 +165,8 @@ def flash_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, Sq, D), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        compiler_params=compiler_params("parallel", "arbitrary",
+                                        "arbitrary"),
         interpret=interpret,
         name=kernel_name("flash_attention"),
     )(q_lens.astype(jnp.int32), kv_lens.astype(jnp.int32),

@@ -22,8 +22,20 @@ from __future__ import annotations
 import dataclasses
 import re
 
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.cyclemodel import VMEM_LIMIT_BYTES
+
 __all__ = ["ScheduleContract", "register_family", "kernel_name",
-           "contract_for", "registered_families"]
+           "contract_for", "registered_families", "compiler_params"]
+
+
+def compiler_params(*dimension_semantics: str) -> pltpu.CompilerParams:
+    """Mosaic parameters shared by every kernel: the grid semantics
+    plus the scoped VMEM limit the tuner's space budgets against
+    (:data:`repro.core.cyclemodel.VMEM_LIMIT_BYTES`)."""
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 @dataclasses.dataclass(frozen=True)

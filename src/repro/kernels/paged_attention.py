@@ -34,8 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-from repro.kernels.meta import kernel_name, register_family
+from repro.kernels.meta import (compiler_params, kernel_name,
+                                 register_family)
 
 _META = register_family("paged_attention", grid_rank=2,
                         managed_dma=False, sequential_axes="last")
@@ -60,8 +60,8 @@ def _kernel(pt_ref, kl_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0]                       # (rep, D)
-    k = k_ref[0, :, 0]                 # (ps, D) — the gathered page
-    v = v_ref[0, :, 0]                 # (ps, D)
+    k = k_ref[0]                       # (ps, D) — the gathered page
+    v = v_ref[0]                       # (ps, D)
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
@@ -114,6 +114,14 @@ def paged_attention(
     # of one grid step, so each gathered page is read once per kv head.
     qf = q.reshape(B * KV, rep, D)
     pt_flat = page_table.reshape(-1).astype(jnp.int32)     # (B*T,)
+    # Each grid step reads one kv head of one page.  On the (P, ps, KV,
+    # D) pool that block would be (1, ps, 1, D), whose second-minor
+    # dimension (1 of KV) Mosaic refuses for KV > 1; folding the heads
+    # into the lane axis (a free reshape) makes it a (ps, D) tile: a
+    # full-extent sublane dimension and a 128-lane-aligned column
+    # window at head g % KV.
+    kf = k_pool.reshape(P, ps, KV * D)
+    vf = v_pool.reshape(P, ps, KV * D)
 
     kernel = functools.partial(_kernel, scale=scale, ps=ps, kv_heads=KV)
     # K/V index maps do the page-table walk: grid step (g, j) pulls
@@ -125,11 +133,11 @@ def paged_attention(
         in_specs=[
             pl.BlockSpec((1, rep, D), lambda g, j, *_: (g, 0, 0)),
             pl.BlockSpec(
-                (1, ps, 1, D),
-                lambda g, j, pt, kl: (pt[(g // KV) * T + j], 0, g % KV, 0)),
+                (1, ps, D),
+                lambda g, j, pt, kl: (pt[(g // KV) * T + j], 0, g % KV)),
             pl.BlockSpec(
-                (1, ps, 1, D),
-                lambda g, j, pt, kl: (pt[(g // KV) * T + j], 0, g % KV, 0)),
+                (1, ps, D),
+                lambda g, j, pt, kl: (pt[(g // KV) * T + j], 0, g % KV)),
         ],
         out_specs=pl.BlockSpec((1, rep, D), lambda g, j, *_: (g, 0, 0)),
         scratch_shapes=[
@@ -142,9 +150,8 @@ def paged_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * KV, rep, D), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=compiler_params("parallel", "arbitrary"),
         interpret=interpret,
         name=kernel_name("paged_attention"),
-    )(pt_flat, kv_lens.astype(jnp.int32), qf, k_pool, v_pool)
+    )(pt_flat, kv_lens.astype(jnp.int32), qf, kf, vf)
     return of.reshape(B, H, D)

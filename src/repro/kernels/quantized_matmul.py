@@ -35,8 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-from repro.kernels.meta import kernel_name, register_family
+from repro.kernels.meta import (compiler_params, kernel_name,
+                                 register_family)
 from repro.kernels.zero_stall_matmul import resolve_slots
 
 _META = register_family("quantized_zero_stall_matmul", grid_rank=3,
@@ -202,8 +202,8 @@ def quantized_zero_stall_matmul(
             pltpu.SemaphoreType.DMA((slots,)),
             pltpu.SemaphoreType.DMA((slots,)),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        compiler_params=compiler_params("arbitrary", "arbitrary",
+                                        "arbitrary"),
         interpret=interpret,
         name=kernel_name("quantized_zero_stall_matmul", slots=slots,
                          grid_order=grid_order),
@@ -332,8 +332,7 @@ def quantized_grouped_zero_stall_matmul(
             pltpu.SemaphoreType.DMA((slots,)),
             pltpu.SemaphoreType.DMA((slots,)),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",) * 4),
+        compiler_params=compiler_params(*("arbitrary",) * 4),
         interpret=interpret,
         name=kernel_name("quantized_grouped_zero_stall_matmul",
                          slots=slots),

@@ -53,8 +53,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-from repro.kernels.meta import kernel_name, register_family
+from repro.kernels.meta import (compiler_params, kernel_name,
+                                 register_family)
 # canonical (variant, slots) rules — shared with KernelConfig validation
 from repro.plan.config import resolve_slots
 
@@ -213,9 +213,8 @@ def zero_stall_matmul(
             pltpu.SemaphoreType.DMA((slots,)),
             pltpu.SemaphoreType.DMA((slots,)),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-        ),
+        compiler_params=compiler_params("arbitrary", "arbitrary",
+                                        "arbitrary"),
         interpret=interpret,
         name=kernel_name("zero_stall_matmul", slots=slots,
                          grid_order=grid_order),

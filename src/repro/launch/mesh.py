@@ -12,31 +12,22 @@ jax device state (the dry-run sets XLA_FLAGS before any jax init).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-try:  # AxisType landed in jax ~0.5; older stacks imply Auto everywhere
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - exercised on jax<=0.4.x
-    AxisType = None
-
-__all__ = ["make_production_mesh", "make_host_mesh", "make_mesh_compat"]
+__all__ = ["make_production_mesh", "make_host_mesh", "make_mesh"]
 
 
-def make_mesh_compat(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
-    """jax.make_mesh across jax versions (axis_types when supported)."""
-    if AxisType is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(AxisType.Auto,) * len(axes))
-        except TypeError:  # make_mesh predates the axis_types kwarg
-            pass
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD propagation —
+    the sharding rules place params and constrain activations; nothing
+    is typed ``Explicit``)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(*, data: int | None = None, model: int = 1) -> Mesh:
@@ -44,4 +35,4 @@ def make_host_mesh(*, data: int | None = None, model: int = 1) -> Mesh:
     n = jax.device_count()
     data = data if data is not None else n // model
     assert data * model <= n, f"mesh {data}x{model} > {n} devices"
-    return make_mesh_compat((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

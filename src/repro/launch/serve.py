@@ -10,6 +10,12 @@ the masked flash-attention kernel.
 
   PYTHONPATH=src python -m repro.launch.serve --arch gemma-7b --reduced \
       --batch 8 --num-slots 4 --prompt-len 32 --gen-len 32 --mixed
+
+``--no-reduced`` serves the published widths.  The backend defaults to
+``auto`` (the Pallas kernels on a TPU, the jnp reference elsewhere),
+and params and compute default to bfloat16.  With ``--replicas N`` each
+replica engine lives on its own device (round-robin over
+``jax.devices()``).
 """
 
 from __future__ import annotations
@@ -23,6 +29,7 @@ import numpy as np
 
 from repro import obs
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import Ctx, build_model
 from repro.serve import Request, Router, ServeEngine, ShardedEngine
 
@@ -31,13 +38,13 @@ __all__ = ["serve_batch"]
 
 def _parse_mesh(spec: str):
     """``"DxM"`` -> a ('data', 'model') mesh over the local devices."""
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     try:
         d, m = (int(x) for x in spec.lower().split("x"))
     except ValueError:
         raise ValueError(f"--mesh must look like 'DxM' (e.g. 1x8), "
                          f"got {spec!r}") from None
-    return make_mesh_compat((d, m), ("data", "model"))
+    return make_mesh((d, m), ("data", "model"))
 
 
 def _make_requests(cfg, key, batch: int, prompt_len: int, gen_len: int,
@@ -70,8 +77,8 @@ def _make_requests(cfg, key, batch: int, prompt_len: int, gen_len: int,
 
 def serve_batch(arch: str, *, reduced: bool = True, batch: int = 4,
                 prompt_len: int = 32, gen_len: int = 32, seed: int = 0,
-                dtype=jnp.float32, num_slots: int | None = None,
-                mixed: bool = False, impl: str = "jnp",
+                dtype=jnp.bfloat16, num_slots: int | None = None,
+                mixed: bool = False, impl: str = "auto",
                 steps_per_dispatch: int = 1, temperature: float = 0.0,
                 top_k: int = 0, top_p: float = 1.0,
                 plan=None, plan_out: str | None = None,
@@ -85,7 +92,10 @@ def serve_batch(arch: str, *, reduced: bool = True, batch: int = 4,
                 kill_at_step: int = 2) -> dict:
     """Run a synthetic request batch through the serving engine.
 
-    ``impl`` is the backend; ``plan`` is forwarded to
+    ``impl`` is the backend; ``dtype`` is both the params' storage
+    and the compute dtype (weights are built in it by a jitted init,
+    with no float32 copy of a whole layer stack); ``plan`` is forwarded
+    to
     :class:`~repro.serve.ServeEngine` (a :class:`repro.plan.Plan`, a
     path to a saved plan JSON, or ``"trace"`` to resolve every kernel
     config ahead of time); ``plan_out`` saves the engine's active plan
@@ -105,7 +115,8 @@ def serve_batch(arch: str, *, reduced: bool = True, batch: int = 4,
 
     Cluster knobs (:mod:`repro.serve.cluster`): ``replicas`` fronts N
     data-parallel engine replicas with a :class:`repro.serve.Router`
-    (load-aware placement, fault-tolerant re-queue); ``mesh`` (e.g.
+    (load-aware placement, fault-tolerant re-queue), replica ``i`` on
+    device ``i % jax.device_count()``; ``mesh`` (e.g.
     ``"1x8"``) runs each engine as a :class:`repro.serve.ShardedEngine`
     over a ('data', 'model') device mesh (model-parallel decode);
     ``kill_replica`` fails that replica at router step
@@ -117,7 +128,7 @@ def serve_batch(arch: str, *, reduced: bool = True, batch: int = 4,
     model = build_model(cfg)
     ctx = Ctx(plan=impl, dtype=dtype)
     key = jax.random.PRNGKey(seed)
-    params = model.init(key, dtype=jnp.float32)
+    params = jax.jit(model.init, static_argnames="dtype")(key, dtype=dtype)
 
     if isinstance(plan, str) and plan != "trace":
         plan = Plan.load(plan)
@@ -132,7 +143,7 @@ def serve_batch(arch: str, *, reduced: bool = True, batch: int = 4,
     cache_kwargs = {"enc_len": prompt_len} if cfg.family == "encdec" else None
     device_mesh = _parse_mesh(mesh) if mesh is not None else None
 
-    def make_engine():
+    def make_engine(device=None):
         kw = dict(num_slots=slots, max_len=max_len, cache_dtype=dtype,
                   steps_per_dispatch=steps_per_dispatch, seed=seed,
                   cache_kwargs=cache_kwargs, plan=plan,
@@ -140,13 +151,16 @@ def serve_batch(arch: str, *, reduced: bool = True, batch: int = 4,
                   num_pages=num_pages, prefill_chunk=prefill_chunk)
         if device_mesh is not None:
             return ShardedEngine(model, params, ctx, mesh=device_mesh, **kw)
-        return ServeEngine(model, params, ctx, **kw)
+        return ServeEngine(model, params, ctx, device=device, **kw)
 
     reqs = _make_requests(cfg, key, batch, prompt_len, gen_len, mixed,
                           temperature=temperature, top_k=top_k, top_p=top_p)
     cluster: dict | None = None
     if replicas > 1:
-        engines = [make_engine() for _ in range(replicas)]
+        devices = jax.devices()
+        engines = [make_engine(None if device_mesh is not None
+                               else devices[i % len(devices)])
+                   for i in range(replicas)]
         router = Router(engines, validate=validate_plan,
                         step_timeout_s=step_timeout_s)
         for r in reqs:
@@ -206,15 +220,23 @@ def serve_batch(arch: str, *, reduced: bool = True, batch: int = 4,
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma-7b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="the config's smoke-test widths (--no-reduced: "
+                         "the published widths)")
+    ap.add_argument("--dtype", default="bfloat16",
+                    choices=["bfloat16", "float32"],
+                    help="params and compute dtype")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--num-slots", type=int, default=None)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen-len", type=int, default=32)
     ap.add_argument("--mixed", action="store_true",
                     help="mixed prompt lengths (ragged traffic)")
-    ap.add_argument("--impl", default="jnp",
-                    choices=["auto", "jnp", "pallas", "interpret"])
+    ap.add_argument("--impl", default="auto",
+                    choices=["auto", "jnp", "pallas", "interpret"],
+                    help="kernel backend (auto: Pallas on a TPU, the jnp "
+                         "reference elsewhere)")
     ap.add_argument("--steps-per-dispatch", type=int, default=1,
                     help="decode+sample iterations fused into one jitted "
                          "dispatch (one host sync per block)")
@@ -272,6 +294,7 @@ def main():
                     help="write a JSONL span/event trace of the run here "
                          "(implies tracing on; one JSON object per line)")
     args = ap.parse_args()
+    use_compile_cache()
 
     # --trace-out / --metrics turn observability on for the run:
     # spans/events stream to the JSONL sink (if any), kernel dispatches
@@ -282,6 +305,7 @@ def main():
         obs.enable()
     try:
         out = serve_batch(args.arch, reduced=args.reduced, batch=args.batch,
+                          dtype=jnp.dtype(args.dtype),
                           prompt_len=args.prompt_len, gen_len=args.gen_len,
                           num_slots=args.num_slots, mixed=args.mixed,
                           impl=args.impl, seed=args.seed,

@@ -144,29 +144,7 @@ def shard_act(x: jax.Array, ctx: "Ctx") -> jax.Array:
     # fp32 upcast of the *whole* (layers, B, S, d) saved-residual stack
     # out of the backward loop (measured: +16.5 GiB/device on
     # mistral-large-123b).  The barrier keeps per-layer slices inside.
-    return _opt_barrier(y)
-
-
-@jax.custom_vjp
-def _opt_barrier(x: jax.Array) -> jax.Array:
-    """optimization_barrier with reverse-mode AD on any jax version.
-
-    jax < 0.5 has no differentiation rule for the primitive; this vjp
-    mirrors the upstream rule (barrier the cotangent too, so the
-    backward pass gets the same hoisting protection).
-    """
-    return jax.lax.optimization_barrier(x)
-
-
-def _opt_barrier_fwd(x):
-    return jax.lax.optimization_barrier(x), None
-
-
-def _opt_barrier_bwd(_, g):
-    return (jax.lax.optimization_barrier(g),)
-
-
-_opt_barrier.defvjp(_opt_barrier_fwd, _opt_barrier_bwd)
+    return jax.lax.optimization_barrier(y)
 
 
 # ----------------------------------------------------------------------
@@ -186,7 +164,47 @@ def init_linear(key, d_in: int, d_out: int, *, bias: bool = False,
     return p
 
 
-def linear(p: Params, x: jax.Array, ctx: Ctx) -> jax.Array:
+def _kernels_on_mesh(ctx: "Ctx | None") -> bool:
+    """Pallas calls under a multi-device mesh need an explicit
+    ``shard_map``: Mosaic kernels cannot be partitioned by GSPMD."""
+    return (ctx is not None and ctx.mesh is not None and ctx.mesh.size > 1
+            and ops.resolve_impl(ctx.plan.backend) in ("pallas", "interpret"))
+
+
+def _model_split(mesh, dim: int) -> str | None:
+    """'model' when that axis exists and divides ``dim``, else None."""
+    tp = dict(mesh.shape).get("model", 1)
+    return "model" if tp > 1 and dim % tp == 0 else None
+
+
+def _matmul(x2: jax.Array, w: jax.Array, ctx: Ctx, *, out_dtype,
+            split: str | None = None) -> jax.Array:
+    """``ops.matmul``; under a mesh with kernels, Megatron tensor
+    parallelism through ``shard_map``: ``split="col"`` keeps each
+    chip's slice of w's output columns (output sharded the same way),
+    ``split="row"`` contracts each chip's slice of the input features
+    and sums the float32 partial products over 'model'.  Anything
+    unsplit runs replicated."""
+    if not _kernels_on_mesh(ctx):
+        return ops.matmul(x2, w, config=ctx.plan, out_dtype=out_dtype)
+    from jax.sharding import PartitionSpec as P
+    col = split == "col" and _model_split(ctx.mesh, w.shape[1])
+    row = split == "row" and _model_split(ctx.mesh, w.shape[0])
+    specs = ((P(), P(None, "model"), P(None, "model")) if col else
+             (P(None, "model"), P("model", None), P()) if row else
+             (P(), P(), P()))
+
+    def local(x, w):
+        if not row:
+            return ops.matmul(x, w, config=ctx.plan, out_dtype=out_dtype)
+        part = ops.matmul(x, w, config=ctx.plan, out_dtype=jnp.float32)
+        return jax.lax.psum(part, "model").astype(out_dtype)
+    return jax.shard_map(local, mesh=ctx.mesh, in_specs=specs[:2],
+                         out_specs=specs[2], check_vma=False)(x2, w)
+
+
+def linear(p: Params, x: jax.Array, ctx: Ctx, *,
+           split: str | None = None) -> jax.Array:
     """x: (..., d_in) @ w -> (..., d_out) through the zero-stall engine.
 
     :class:`~repro.quant.QTensor` weights (``Model.quantize_weights``)
@@ -195,7 +213,8 @@ def linear(p: Params, x: jax.Array, ctx: Ctx) -> jax.Array:
     dequant epilogue); anything else dequantizes the weight on the fly
     and runs the standard kernel — so fp8-simulated and opted-out
     quantized params still execute on the Pallas path, never a jnp
-    fallback.
+    fallback.  ``split`` ("col" | "row") is this layer's place in
+    Megatron tensor parallelism (see :func:`_matmul`).
     """
     w = p["w"]
     lead = x.shape[:-1]
@@ -210,7 +229,7 @@ def linear(p: Params, x: jax.Array, ctx: Ctx) -> jax.Array:
         d_out = w.shape[-1]
     else:
         w = w.astype(ctx.dtype)
-        y = ops.matmul(x2, w, config=ctx.plan, out_dtype=ctx.dtype)
+        y = _matmul(x2, w, ctx, out_dtype=ctx.dtype, split=split)
         d_out = w.shape[-1]
     y = y.reshape(*lead, d_out)
     if "b" in p:
@@ -268,9 +287,11 @@ def init_attention(key, cfg: ModelConfig, dtype=jnp.float32) -> Params:
 def _qkv(p: Params, x: jax.Array, cfg: ModelConfig, ctx: Ctx):
     B, S, _ = x.shape
     hd = cfg.resolved_head_dim
-    q = linear(p["wq"], x, ctx).reshape(B, S, cfg.n_heads, hd)
-    k = linear(p["wk"], x, ctx).reshape(B, S, cfg.n_kv_heads, hd)
-    v = linear(p["wv"], x, ctx).reshape(B, S, cfg.n_kv_heads, hd)
+    q = linear(p["wq"], x, ctx, split="col").reshape(B, S, cfg.n_heads, hd)
+    k = linear(p["wk"], x, ctx, split="col").reshape(B, S, cfg.n_kv_heads,
+                                                     hd)
+    v = linear(p["wv"], x, ctx, split="col").reshape(B, S, cfg.n_kv_heads,
+                                                     hd)
     return q, k, v
 
 
@@ -364,7 +385,7 @@ def _attn_config(config, impl: str):
 def _gqa_full(q, k, v, *, causal: bool, impl: str,
               ctx: "Ctx | None" = None, config="auto",
               lengths: jax.Array | None = None,
-              q_offset: jax.Array | None = None) -> jax.Array:
+              q_offset: jax.Array | None = None, mesh=None) -> jax.Array:
     """q: (B,S,H,D), k/v: (B,T,KV,D) -> (B,S,H,D).
 
     Under a mesh, KV heads are repeated up to H ("merged-head" form) so
@@ -380,9 +401,10 @@ def _gqa_full(q, k, v, *, causal: bool, impl: str,
     rows produce zeros.  ``q_offset``: optional (B,) absolute position
     of query row 0 (chunked prefill — requires ``lengths``).  On the
     Pallas path this stays on the kernel via its length/offset
-    operands; on the jnp path the score mask gains a batch dimension
-    (the chunked variants are skipped — serving prompts are far below
-    the chunk threshold).
+    operands — head-parallel through ``shard_map`` under a
+    multi-device ``mesh`` — and on the jnp path the score mask gains a
+    batch dimension (the chunked variants are skipped — serving
+    prompts are far below the chunk threshold).
     """
     B, S, H, D = q.shape
     KV = k.shape[2]
@@ -392,10 +414,18 @@ def _gqa_full(q, k, v, *, causal: bool, impl: str,
         # flash kernel wants (B, H, S, D) with matched head counts
         kr = jnp.repeat(k, rep, axis=2).transpose(0, 2, 1, 3)
         vr = jnp.repeat(v, rep, axis=2).transpose(0, 2, 1, 3)
-        o = ops.attention(q.transpose(0, 2, 1, 3), kr, vr,
-                          config=_attn_config(config, impl), causal=causal,
-                          q_lens=lengths, kv_lens=lengths,
-                          q_offsets=q_offset)
+
+        def attend(q, k, v, lens, off):
+            return ops.attention(q, k, v, config=_attn_config(config, impl),
+                                 causal=causal, q_lens=lens, kv_lens=lens,
+                                 q_offsets=off)
+        if mesh is not None and mesh.size > 1:
+            from jax.sharding import PartitionSpec as P
+            heads = P(None, _model_split(mesh, H), None, None)
+            attend = jax.shard_map(attend, mesh=mesh,
+                                   in_specs=(heads, heads, heads, P(), P()),
+                                   out_specs=heads, check_vma=False)
+        o = attend(q.transpose(0, 2, 1, 3), kr, vr, lengths, q_offset)
         return o.transpose(0, 2, 1, 3)
     # merged-head path (callers gate via _merged_head_plan):
     if ctx is not None and ctx.mesh is not None:
@@ -602,10 +632,11 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig, ctx: Ctx, *,
     o = _gqa_full(q, k, v, causal=causal,
                   impl=ops.resolve_impl(ctx.plan.backend),
                   ctx=ctx if n_pad is not None else None,
-                  config=ctx.plan, lengths=lengths)
+                  config=ctx.plan, lengths=lengths, mesh=ctx.mesh)
     if n_pad:
         o = o[:, :, :cfg.n_heads]
-    return linear(p["wo"], o.reshape(B, S, cfg.n_heads * hd), ctx)
+    return linear(p["wo"], o.reshape(B, S, cfg.n_heads * hd), ctx,
+                  split="row")
 
 
 def attention_decode(p: Params, x: jax.Array, cfg: ModelConfig, ctx: Ctx, *,
@@ -640,7 +671,7 @@ def attention_decode(p: Params, x: jax.Array, cfg: ModelConfig, ctx: Ctx, *,
     probs = jax.nn.softmax(logits, axis=-1)
     o = jnp.einsum("bkrst,btkd->bskrd", probs.astype(cv.dtype), cv)
     o = o.reshape(B, 1, cfg.n_heads * hd)
-    return linear(p["wo"], o, ctx), {"k": ck, "v": cv}
+    return linear(p["wo"], o, ctx, split="row"), {"k": ck, "v": cv}
 
 
 def attention_decode_paged(p: Params, x: jax.Array, cfg: ModelConfig,
@@ -680,7 +711,7 @@ def attention_decode_paged(p: Params, x: jax.Array, cfg: ModelConfig,
             kv_lens=pos_b + 1, config=_attn_config(ctx.plan, impl),
             scale=hd ** -0.5)
         o = o.reshape(B, 1, cfg.n_heads * hd)
-        return linear(p["wo"], o, ctx), {"k": ck, "v": cv}
+        return linear(p["wo"], o, ctx, split="row"), {"k": ck, "v": cv}
     ps = ck.shape[1]
     T = page_table.shape[1]
     kg = ck[page_table].reshape(B, T * ps, KV, hd)
@@ -695,7 +726,7 @@ def attention_decode_paged(p: Params, x: jax.Array, cfg: ModelConfig,
     probs = jax.nn.softmax(logits, axis=-1)
     o = jnp.einsum("bkrst,btkd->bskrd", probs.astype(vg.dtype), vg)
     o = o.reshape(B, 1, cfg.n_heads * hd)
-    return linear(p["wo"], o, ctx), {"k": ck, "v": cv}
+    return linear(p["wo"], o, ctx, split="row"), {"k": ck, "v": cv}
 
 
 def attention_decode_quantized(p: Params, x: jax.Array, cfg: ModelConfig,
@@ -748,7 +779,7 @@ def attention_decode_quantized(p: Params, x: jax.Array, cfg: ModelConfig,
     o = jnp.einsum("bkrst,btkd->bskrd", pv.astype(ctx.dtype),
                    cv.astype(ctx.dtype))
     o = o.reshape(B, 1, cfg.n_heads * hd)
-    out = linear(p["wo"], o, ctx)
+    out = linear(p["wo"], o, ctx, split="row")
     return out, {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs}
 
 
@@ -815,14 +846,14 @@ def init_mlp(key, cfg: ModelConfig, dtype=jnp.float32,
 
 
 def mlp(p: Params, x: jax.Array, cfg: ModelConfig, ctx: Ctx) -> jax.Array:
-    h = linear(p["wi"], x, ctx)
+    h = linear(p["wi"], x, ctx, split="col")
     if cfg.mlp_type == "swiglu":
-        h = jax.nn.silu(linear(p["wg"], x, ctx)) * h
+        h = jax.nn.silu(linear(p["wg"], x, ctx, split="col")) * h
     elif cfg.mlp_type == "geglu":
-        h = jax.nn.gelu(linear(p["wg"], x, ctx)) * h
+        h = jax.nn.gelu(linear(p["wg"], x, ctx, split="col")) * h
     else:
         h = jax.nn.gelu(h)
-    return linear(p["wo"], h, ctx)
+    return linear(p["wo"], h, ctx, split="row")
 
 
 # ----------------------------------------------------------------------
@@ -853,8 +884,8 @@ def unembed(p: Params, x: jax.Array, ctx: Ctx) -> jax.Array:
     else:
         w = p["tokens"].astype(ctx.dtype).T
     B, S, d = x.shape
-    logits = ops.matmul(x.reshape(B * S, d), w, config=ctx.plan,
-                        out_dtype=jnp.float32)
+    logits = _matmul(x.reshape(B * S, d), w, ctx, out_dtype=jnp.float32,
+                     split="col")
     return logits.reshape(B, S, w.shape[-1])
 
 

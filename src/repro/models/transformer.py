@@ -245,9 +245,10 @@ def prefill(params: Params, tokens: jax.Array, cfg: ModelConfig, ctx: Ctx,
         k = L.rope(k, positions, cfg.rope_theta)
         o = L._gqa_full(q, k, v, causal=True,
                         impl=L.ops.resolve_impl(ctx.plan.backend), ctx=ctx,
-                        config=ctx.plan, lengths=lens)
+                        config=ctx.plan, lengths=lens, mesh=ctx.mesh)
         x = x + L.linear(lp["attn"]["wo"],
-                         o.reshape(B, S, cfg.n_heads * hd), ctx)
+                         o.reshape(B, S, cfg.n_heads * hd), ctx,
+                         split="row")
         h = L.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
         fn = mlp_fn or (lambda p, v_: (L.mlp(p, v_, cfg, ctx), 0.0))
         y, _ = fn(lp["mlp"], h)
@@ -320,9 +321,11 @@ def prefill_chunk(params: Params, tokens: jax.Array, cfg: ModelConfig,
             lc["v"], v.astype(lc["v"].dtype), (zero, offset, zero, zero))
         o = L._gqa_full(q, ck, cv, causal=True,
                         impl=L.ops.resolve_impl(ctx.plan.backend), ctx=ctx,
-                        config=ctx.plan, lengths=lens, q_offset=off_b)
+                        config=ctx.plan, lengths=lens, q_offset=off_b,
+                        mesh=ctx.mesh)
         x = x + L.linear(lp["attn"]["wo"],
-                         o.reshape(B, C, cfg.n_heads * hd), ctx)
+                         o.reshape(B, C, cfg.n_heads * hd), ctx,
+                         split="row")
         h = L.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
         fn = mlp_fn or (lambda p, v_: (L.mlp(p, v_, cfg, ctx), 0.0))
         y, _ = fn(lp["mlp"], h)

@@ -51,9 +51,7 @@ __all__ = ["QTensor", "quantize", "quantize_rows", "quantize_tree",
 INT8_MAX = 127.0          # symmetric: -127..127 (never -128, keeps |q| even)
 FP8_MAX = 448.0           # float8_e4m3 largest finite magnitude
 
-# e4m3 is present in jax 0.4.x via ml_dtypes; degrade to a bf16 carrier
-# if a stack ever lacks it (the *grid* rounding below is what matters).
-_FP8_DTYPE = getattr(jnp, "float8_e4m3fn", None) or jnp.bfloat16
+_FP8_DTYPE = jnp.float8_e4m3fn
 
 
 @jax.tree_util.register_pytree_node_class

@@ -24,20 +24,8 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:                                     # jax >= 0.5
-    from jax import shard_map
-except ImportError:                      # jax 0.4.x: experimental home
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-        # check_vma has no 0.4.x equivalent: check_rep=False would also
-        # disable the replication *rewrite* that lets rank-0 P() outputs
-        # (our psum'd loss) through, so keep the old default (True).
-        del check_vma
-        return _old_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
 
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
@@ -88,7 +76,7 @@ def pp_loss_fn(params: Any, batch: dict, cfg: ModelConfig, ctx: Ctx,
     def run(stage_layers, embed_p, final_norm_p, tok_tgt):
         tok_mb_, tgt_mb_ = tok_tgt
         p = jax.lax.axis_index(axis)
-        n_p = n_stages          # static (jax.lax.axis_size needs jax>=0.5)
+        n_p = jax.lax.axis_size(axis)
         stage_layers = jax.tree.map(lambda x: x[0], stage_layers)
         positions = jnp.broadcast_to(jnp.arange(S)[None, :], (mb, S))
 
@@ -121,18 +109,14 @@ def pp_loss_fn(params: Any, batch: dict, cfg: ModelConfig, ctx: Ctx,
             idx_l = jnp.clip(t - (n_p - 1), 0, M - 1)
             mb_loss = L.cross_entropy(logits, tgt_mb_[idx_l])
             take = active & (p == n_p - 1)
-            # (1,)-shaped accumulator: a rank-0 loop carry becomes a
-            # rank-0 shard_map residual, whose cotangent fails the
-            # transpose-side spec check on jax 0.4.x (the linearize
-            # side adds a singleton axis, the transpose side doesn't)
-            loss_sum = loss_sum + jnp.where(take, mb_loss, 0.0)[None]
+            loss_sum = loss_sum + jnp.where(take, mb_loss, 0.0)
             recv = jax.lax.ppermute(y, axis, perm)
             return recv, loss_sum
 
         recv, loss_sum = jax.lax.fori_loop(
-            0, M + n_p - 1, tick, (zero_act, jnp.zeros((1,), jnp.float32)))
+            0, M + n_p - 1, tick, (zero_act, jnp.zeros((), jnp.float32)))
         # only the last stage holds the loss; share it
-        loss = jax.lax.psum(loss_sum[0], axis) / M
+        loss = jax.lax.psum(loss_sum, axis) / M
         for a in other_axes:
             loss = jax.lax.pmean(loss, a)
         return loss

@@ -47,9 +47,10 @@ ragged continuous batches stay on the Pallas kernel.
 
 from repro.serve import sampling
 from repro.serve.cluster import Router, ShardedEngine
-from repro.serve.engine import ServeEngine, lockstep_generate
+from repro.serve.engine import ServeEngine, lockstep_generate, paged_view
 from repro.serve.request import GenerationResult, Request
 from repro.serve.stats import EngineStats
 
 __all__ = ["ServeEngine", "EngineStats", "Request", "GenerationResult",
-           "Router", "ShardedEngine", "lockstep_generate", "sampling"]
+           "Router", "ShardedEngine", "lockstep_generate", "paged_view",
+           "sampling"]

@@ -73,7 +73,7 @@ from repro.serve.paging import (TRASH_PAGE, OutOfPages, PageAllocator,
 from repro.serve.request import GenerationResult, Request, SlotState
 from repro.serve.stats import EngineStats
 
-__all__ = ["ServeEngine", "lockstep_generate"]
+__all__ = ["ServeEngine", "lockstep_generate", "paged_view"]
 
 # families whose prefill K/V at position i depends only on tokens <= i
 # AND is batch-composition independent — the prefix-sharing soundness
@@ -176,6 +176,11 @@ class ServeEngine:
         decode dispatches) instead of one monolithic prefill, bounding
         the head-of-line TTFT penalty a long prompt imposes on queued
         short requests.  Requires ``Model.prefill_chunk`` (dense/vlm).
+    device : optional ``jax.Device`` this engine lives on.  Params
+        (copied there unless already resident), cache and sampling
+        keys are placed on it, and every jitted dispatch follows its
+        committed operands — so N replicas built with N devices serve
+        from N chips.  ``None`` keeps JAX's default placement.
     """
 
     def __init__(self, model, params, ctx, *, num_slots: int = 4,
@@ -187,9 +192,13 @@ class ServeEngine:
                  plan=None, validate: bool = False,
                  page_size: int | None = None,
                  num_pages: int | None = None,
-                 prefill_chunk: int | None = None):
+                 prefill_chunk: int | None = None,
+                 device: jax.Device | None = None):
         self.model = model
+        if device is not None:
+            params = jax.device_put(params, device)
         self.params = params
+        self.device = device
         self.num_slots = int(num_slots)
         self.max_len = int(max_len)
         self.steps_per_dispatch = int(steps_per_dispatch)
@@ -284,6 +293,9 @@ class ServeEngine:
         # host mirrors shipped with each dispatch (traced operands, so
         # heterogeneous requests share one compiled program)
         self._keys = sampling.make_keys(self.num_slots)
+        if device is not None:
+            self.cache, self._keys = jax.device_put(
+                (self.cache, self._keys), device)
         self._temp = np.zeros((self.num_slots,), np.float32)
         self._topk = np.zeros((self.num_slots,), np.int32)
         self._topp = np.ones((self.num_slots,), np.float32)
@@ -985,14 +997,48 @@ class ServeEngine:
 
 
 # ----------------------------------------------------------------------
+def paged_view(cache: dict, page_size: int) -> dict:
+    """A contiguous dense-family cache laid out as a page pool.
+
+    ``k``/``v`` ``(L, B, max_len, KV, D)`` become ``(L, B*T, ps, KV,
+    D)`` pools (``T = max_len // page_size``), and row ``b``'s table
+    maps its logical pages to ``b*T .. b*T + T-1`` — the same values a
+    paged engine holds, read through the same paged decode path."""
+    k = cache["k"]
+    L_, B, S = k.shape[:3]
+    if S % page_size:
+        raise ValueError(f"page_size {page_size} must divide {S}")
+    T = S // page_size
+
+    def pool(x):
+        return x.reshape(L_, B * T, page_size, *x.shape[3:])
+    table = jnp.arange(B * T, dtype=jnp.int32).reshape(B, T)
+    return {**cache, "k": pool(k), "v": pool(cache["v"]),
+            "page_table": table}
+
+
 def lockstep_generate(model, params, ctx, prompts: Sequence[Sequence[int]],
                       max_new_tokens: int | Sequence[int], *,
-                      max_len: int, frontend_embeds=None
-                      ) -> list[list[int]]:
+                      max_len: int, frontend_embeds=None,
+                      page_size: int | None = None,
+                      return_logits: bool = False,
+                      forced: Sequence[Sequence[int]] | None = None):
     """Greedy lock-step oracle: one ragged batch, fused prefill, then
     synchronized decode.  The continuous-batching engine must match
     this token-for-token per request (row-independent families) at
-    every ``steps_per_dispatch``."""
+    every ``steps_per_dispatch``.  ``page_size`` (dense family) decodes
+    through the paged attention path over :func:`paged_view` of the
+    prefilled cache — the oracle for a paged engine on a backend whose
+    paged kernel is not bitwise equal to the contiguous decode math.
+    ``return_logits`` also returns each request's float32 logits at
+    every emitted step, ``(B, max(max_new_tokens), V)``, for checks
+    that must tell a rounding-level near-tie from a real divergence.
+    ``forced`` (one token list per prompt, e.g. an engine's output)
+    teacher-forces the decode: each step feeds the forced token instead
+    of the oracle's own pick, so the returned picks (and logits) are
+    the oracle's verdict on every step of that continuation given the
+    same history.  Equal to the free-running oracle up to the first
+    step where the forced token differs from its pick."""
     B = len(prompts)
     if isinstance(max_new_tokens, int):
         max_new = [max_new_tokens] * B
@@ -1007,15 +1053,29 @@ def lockstep_generate(model, params, ctx, prompts: Sequence[Sequence[int]],
              "lengths": jnp.asarray(lens, jnp.int32)}
     if frontend_embeds is not None:
         batch["frontend_embeds"] = jnp.asarray(frontend_embeds)
-    logits, cache = model.prefill(params, batch, ctx, max_len)
+    logits, cache = jax.jit(
+        lambda p, b: model.prefill(p, b, ctx, max_len))(params, batch)
+    if page_size is not None:
+        cache = paged_view(cache, page_size)
     tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
     outs = [[int(t)] for t in np.asarray(tok)]
+    steps = [np.asarray(logits[:, -1], np.float32)] if return_logits else []
     decode = jax.jit(lambda p, c, t: model.decode(p, c, t, ctx),
                      donate_argnums=(1,))
-    for _ in range(max(max_new) - 1):
+    if forced is not None:
+        feed = np.zeros((B, max(max_new)), np.int32)
+        for i, f in enumerate(forced):
+            feed[i, :len(f)] = list(f)[:max(max_new)]
+    for step in range(max(max_new) - 1):
+        if forced is not None:
+            tok = jnp.asarray(feed[:, step])
         logits, cache = decode(params, cache, tok[:, None])
         tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        if return_logits:
+            steps.append(np.asarray(logits[:, -1], np.float32))
         for i, t in enumerate(np.asarray(tok)):
             if len(outs[i]) < max_new[i]:
                 outs[i].append(int(t))
+    if return_logits:
+        return outs, np.stack(steps, axis=1)
     return outs

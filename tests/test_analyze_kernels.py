@@ -170,8 +170,8 @@ def _s1_overlap(a, b):
     return pl.pallas_call(
         _s1_overlap_kernel,
         grid=(gi, gj, gk),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-                  pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+                  pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
         out_specs=pl.BlockSpec((_BM, _BN), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((16, 16), jnp.float32),
         scratch_shapes=[
@@ -181,8 +181,8 @@ def _s1_overlap(a, b):
             pltpu.SemaphoreType.DMA((1,)),
             pltpu.SemaphoreType.DMA((1,)),
         ],
-        compiler_params={
-            "mosaic": {"dimension_semantics": ("arbitrary",) * 3}},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3),
         interpret=True,
         name="zero_stall_matmul_s1_ijk",
     )(a, b)

@@ -382,12 +382,12 @@ def test_validate_rejects_unbounded_requeue_backoff():
 # ShardedEngine
 # ----------------------------------------------------------------------
 def test_sharded_engine_single_device_parity():
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     cfg, model, params = _bundle("gemma-7b")
     lens, max_new = (5, 11, 3, 8), (6, 3, 5, 7)
     baseline = ServeEngine(model, params, CTX, num_slots=2,
                            max_len=32).run(_requests(cfg, lens, max_new))
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sharded = ShardedEngine(model, params, CTX, mesh=mesh, num_slots=2,
                             max_len=32)
     results = sharded.run(_requests(cfg, lens, max_new))
@@ -396,9 +396,9 @@ def test_sharded_engine_single_device_parity():
 
 
 def test_sharded_engine_rejects_paged_cache():
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     cfg, model, params = _bundle("gemma-7b")
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with pytest.raises(ValueError, match="page_size"):
         ShardedEngine(model, params, CTX, mesh=mesh, max_len=32,
                       page_size=4)
@@ -412,7 +412,7 @@ def test_sharded_engine_8_device_parity():
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_config
         from repro.models import Ctx, build_model
-        from repro.launch.mesh import make_mesh_compat
+        from repro.launch.mesh import make_mesh
         from repro.serve import Request, ServeEngine
         from repro.serve.cluster import ShardedEngine
 
@@ -428,7 +428,7 @@ def test_sharded_engine_8_device_parity():
                                                        (6, 3, 5, 7)))]
         base = ServeEngine(model, params, ctx, num_slots=2,
                            max_len=32, steps_per_dispatch=4).run(reqs())
-        mesh = make_mesh_compat((1, 8), ("data", "model"))
+        mesh = make_mesh((1, 8), ("data", "model"))
         eng = ShardedEngine(model, params, ctx, mesh=mesh, num_slots=2,
                             max_len=32, steps_per_dispatch=4)
         sharded_leaves = sum(
@@ -443,6 +443,75 @@ def test_sharded_engine_8_device_parity():
     """
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["PYTHONPATH"] = SRC
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                         capture_output=True, text=True, timeout=520,
+                         env=env)
+    assert out.returncode == 0, f"STDOUT:{out.stdout}\nSTDERR:{out.stderr}"
+    assert "OK" in out.stdout
+
+
+def test_kernels_on_4_device_mesh_and_per_device_replicas():
+    """Subprocess on 4 forced CPU devices, interpret-mode kernels.
+
+    Mosaic kernels cannot be partitioned by GSPMD, so under a mesh the
+    linears run Megatron splits and prefill attention runs
+    head-parallel through shard_map: ShardedEngine's prefill and decode
+    logits must match the unsharded engine's.  Router replicas built
+    with one device each must hold params and cache on that device and
+    still emit the single engine's tokens."""
+    code = """
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.configs import get_config
+        from repro.models import Ctx, build_model
+        from repro.launch.mesh import make_mesh
+        from repro.plan import KernelConfig
+        from repro.serve import Request, Router, ServeEngine
+        from repro.serve.cluster import ShardedEngine
+
+        devices = jax.devices()
+        assert len(devices) == 4
+        cfg = get_config("deepseek-coder-33b", reduced=True)
+        model = build_model(cfg)
+        params = model.init(jax.random.PRNGKey(0), dtype=jnp.float32)
+        ctx = Ctx(plan=KernelConfig(backend="interpret"), dtype=jnp.float32)
+        prompts = [list(np.random.default_rng(i).integers(
+            0, cfg.vocab_size, n)) for i, n in enumerate((5, 11, 3, 8))]
+        reqs = lambda: [Request(rid=i, prompt=p, max_new_tokens=4)
+                        for i, p in enumerate(prompts)]
+        kw = dict(num_slots=2, max_len=32, steps_per_dispatch=2)
+        single = ServeEngine(model, params, ctx, **kw)
+        base = single.run(reqs())
+
+        sharded = ShardedEngine(model, params, ctx,
+                                mesh=make_mesh((1, 4), ("data", "model")),
+                                **kw)
+        batch = {"tokens": jnp.asarray([prompts[1] + [0] * 5], jnp.int32),
+                 "lengths": jnp.asarray([11], jnp.int32)}
+        out = {}
+        for name, eng in (("single", single), ("sharded", sharded)):
+            lg, cache = eng._prefill(eng.params, batch)
+            tok = jnp.asarray([[7]], jnp.int32)
+            dl, _ = jax.jit(lambda p, c, t, e=eng: model.decode(
+                p, c, t, e.ctx))(eng.params, cache, tok)
+            out[name] = (np.asarray(lg), np.asarray(dl))
+        for a, b in zip(out["sharded"], out["single"]):
+            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+        engines = [ServeEngine(model, params, ctx, device=d, **kw)
+                   for d in devices]
+        for eng, d in zip(engines, devices):
+            assert jax.tree.leaves(eng.params)[0].devices() == {d}
+            assert eng.cache["k"].devices() == {d}
+        routed = Router(engines).run(reqs())
+        assert len({r.replica for r in routed.values()}) > 1
+        for i in range(4):
+            assert routed[i].tokens == base[i].tokens, i
+        print("OK")
+    """
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = SRC
     env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],

@@ -35,7 +35,7 @@ def run_py(code: str, devices: int = 8, timeout: int = 520) -> str:
 def test_sharding_rules_and_compile():
     out = run_py("""
         import jax, jax.numpy as jnp, json
-        from repro.launch.mesh import make_mesh_compat
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config
         from repro.models import build_model, Ctx
         from repro.runtime import sharding as shr
@@ -43,7 +43,7 @@ def test_sharding_rules_and_compile():
         from repro.configs import RunConfig
         from repro.core.roofline import analyze_compiled
 
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_config("gemma-7b", reduced=True)
         model = build_model(cfg)
         ctx = Ctx(plan="jnp", dtype=jnp.float32, mesh=mesh)
@@ -84,13 +84,13 @@ def test_real_execution_under_mesh():
     """Actually run (not just compile) a sharded train step on 8 devs."""
     out = run_py("""
         import jax, jax.numpy as jnp
-        from repro.launch.mesh import make_mesh_compat
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config, RunConfig
         from repro.models import build_model, Ctx
         from repro.runtime import sharding as shr
         from repro.optim import init_opt_state, adamw_update
 
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_config("olmoe-1b-7b", reduced=True)
         model = build_model(cfg)
         ctx = Ctx(plan="jnp", dtype=jnp.float32, mesh=mesh)
@@ -122,7 +122,7 @@ def test_real_execution_under_mesh():
 def test_pipeline_parallel_parity():
     out = run_py("""
         import jax, jax.numpy as jnp
-        from repro.launch.mesh import make_mesh_compat
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config
         from repro.models import build_model, Ctx
         from repro.runtime.pipeline_parallel import pp_loss_fn
@@ -136,7 +136,7 @@ def test_pipeline_parallel_parity():
         batch = {"tokens": jax.random.randint(key, (B, S), 0, cfg.vocab_size),
                  "targets": jax.random.randint(key, (B, S), 0, cfg.vocab_size)}
         ref = float(model.loss(params, batch, ctx))
-        mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         pp = float(pp_loss_fn(params, batch, cfg, ctx, mesh,
                               n_microbatches=2))
         assert abs(ref - pp) < 1e-4, (ref, pp)
@@ -152,7 +152,7 @@ def test_pipeline_parallel_parity():
 def test_elastic_restore_smaller_mesh(tmp_path):
     out = run_py(f"""
         import jax, jax.numpy as jnp
-        from repro.launch.mesh import make_mesh_compat
+        from repro.launch.mesh import make_mesh
         from repro.checkpoint import Checkpointer
         from repro.configs import get_config
         from repro.models import build_model
@@ -163,13 +163,13 @@ def test_elastic_restore_smaller_mesh(tmp_path):
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(0), dtype=jnp.float32)
 
-        big = make_mesh_compat((2, 4), ("data", "model"))
+        big = make_mesh((2, 4), ("data", "model"))
         params_big = jax.device_put(params, shr.param_shardings(big, params))
         ck = Checkpointer({str(tmp_path)!r}, keep=1)
         ck.save(10, {{"params": params_big}}, blocking=True)
 
         # "pod loss": restore onto a 4-device mesh
-        small = make_mesh_compat((2, 2), ("data", "model"))
+        small = make_mesh((2, 2), ("data", "model"))
         state, step = elastic_restore(ck, {{"params": params}}, small)
         assert step == 10
         leaves = jax.tree.leaves(state["params"])
@@ -192,13 +192,13 @@ def test_cache_shardings_dp_only_mesh():
     out = run_py("""
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding
-        from repro.launch.mesh import make_mesh_compat
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config
         from repro.models import build_model
         from repro.runtime import sharding as shr
 
         for shape, axes in (((8,), ("data",)), ((2, 4), ("pod", "data"))):
-            mesh = make_mesh_compat(shape, axes)
+            mesh = make_mesh(shape, axes)
             # zamba2 covers KV + conv + ssm leaves, seamless covers
             # cross_k/cross_v — every branch that used to hard-index
             for arch in ("zamba2-2.7b", "seamless-m4t-large-v2"):

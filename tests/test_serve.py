@@ -332,7 +332,37 @@ def test_engine_eos_retires_mid_block():
     for i in range(4):
         assert outs[4][i] == truncate(oracle[i])
     assert outs[4][0][-1] == eos    # request 0 genuinely stopped early
-    assert len(outs[4][0]) == 3
+    # the stop is at eos's FIRST occurrence (greedy may emit it earlier
+    # than index 2, where it was picked)
+    assert len(outs[4][0]) == oracle[0].index(eos) + 1 < 8
+
+
+def test_lockstep_forced_scores_a_continuation():
+    """Teacher-forced on its own tokens the oracle reproduces itself;
+    forced onto another token at step j its picks and logits agree with
+    the free run up to j, and from j on they judge the forced history
+    (which a token-by-token check of that history then sees)."""
+    cfg = get_config("gemma-7b", reduced=True)
+    model = build_model(cfg)
+    params = model.init(KEY, dtype=jnp.float32)
+    prompts = _prompts(cfg.vocab_size)
+    free, free_lg = lockstep_generate(model, params, CTX, prompts, 6,
+                                      max_len=32, return_logits=True)
+    same, same_lg = lockstep_generate(model, params, CTX, prompts, 6,
+                                      max_len=32, return_logits=True,
+                                      forced=free)
+    assert same == free
+    np.testing.assert_array_equal(same_lg, free_lg)
+    j = 2
+    other = [list(t) for t in free]
+    other[0][j] = (other[0][j] + 1) % cfg.vocab_size
+    picks, lg = lockstep_generate(model, params, CTX, prompts, 6,
+                                  max_len=32, return_logits=True,
+                                  forced=other)
+    assert picks[0][:j + 1] == free[0][:j + 1]
+    np.testing.assert_array_equal(lg[0, :j + 1], free_lg[0, :j + 1])
+    assert not np.array_equal(lg[0, j + 1], free_lg[0, j + 1])
+    assert picks[1:] == free[1:]
 
 
 def test_engine_one_host_sync_per_dispatch(monkeypatch):

@@ -262,3 +262,43 @@ def test_page_gauges_in_snapshot_not_in_legacy_shim():
     with pytest.warns(DeprecationWarning):
         legacy = dict(engine.stats)
     assert set(legacy) == set(_LEGACY_KEYS)
+
+
+# ----------------------------------------------------------------------
+# the paged oracle (lockstep_generate(page_size=...))
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("backend", ["jnp", "interpret"])
+def test_paged_oracle_matches_contiguous_oracle(backend):
+    """Decoding through paged_view of the prefilled cache must emit the
+    contiguous oracle's tokens: bitwise-equal math on jnp, the paged
+    Pallas kernel on interpret."""
+    cfg, model, params = _bundle("gemma-7b")
+    prompts = _prompts(cfg.vocab_size)
+    ctx = Ctx(plan=KernelConfig(backend=backend), dtype=jnp.float32)
+    base = lockstep_generate(model, params, ctx, prompts, 5, max_len=32)
+    paged = lockstep_generate(model, params, ctx, prompts, 5, max_len=32,
+                              page_size=4)
+    assert paged == base
+
+
+def test_paged_view_layout():
+    """Row b's logical page t is physical page b*T + t, holding exactly
+    the contiguous positions [t*ps, (t+1)*ps)."""
+    from repro.serve import paged_view
+    L_, B, S, KV, D, ps = 2, 3, 8, 2, 4, 4
+    k = jnp.arange(L_ * B * S * KV * D, dtype=jnp.float32).reshape(
+        L_, B, S, KV, D)
+    view = paged_view({"k": k, "v": -k, "pos": jnp.zeros((B,), jnp.int32)},
+                      ps)
+    T = S // ps
+    assert view["k"].shape == (L_, B * T, ps, KV, D)
+    assert view["page_table"].shape == (B, T)
+    for b in range(B):
+        for t in range(T):
+            page = int(view["page_table"][b, t])
+            np.testing.assert_array_equal(
+                view["k"][:, page], k[:, b, t * ps:(t + 1) * ps])
+            np.testing.assert_array_equal(
+                view["v"][:, page], -k[:, b, t * ps:(t + 1) * ps])
+    with pytest.raises(ValueError, match="page_size"):
+        paged_view({"k": k, "v": k}, 3)
