@@ -16,6 +16,12 @@ Design constraints, in priority order:
    "name": ..., "t": <perf_counter>, ...}``.  Spans add ``dur_s``;
    arbitrary keyword attributes pass through verbatim, so downstream
    tooling is ``json.loads`` per line and nothing else.
+4. **One clock with the device.**  An enabled span also enters a
+   :class:`jax.profiler.TraceAnnotation` of its name, so while a JAX
+   profiler trace runs every span lands on the trace's host plane, on
+   the profiler's own clock, next to the device operations.  The
+   annotation opens before ``t`` is read and closes after ``dur_s``
+   is, so it holds the record's interval.
 
 State is process-global (like :mod:`logging`): kernels, the serving
 engine and benchmarks all emit into whatever sink the entry point
@@ -30,6 +36,8 @@ import json
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Span", "JsonlSink", "ListSink", "span", "event", "enable",
            "disable", "enabled", "capture", "counter_inc", "counters",
@@ -92,20 +100,24 @@ class ListSink:
 # spans and events
 # ----------------------------------------------------------------------
 class Span:
-    """Timed context manager; emits one record on exit."""
+    """Timed context manager; emits one record on exit and is a
+    profiler annotation of its name while it is open."""
 
-    __slots__ = ("name", "attrs", "t0")
+    __slots__ = ("name", "attrs", "t0", "_annotation")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
         self.attrs = attrs
 
     def __enter__(self) -> "Span":
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
         sink = _STATE.sink
         if sink is not None:
             record = {"type": "span", "name": self.name, "t": self.t0,

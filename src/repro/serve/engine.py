@@ -642,17 +642,19 @@ class ServeEngine:
         """Sample the request's first token from its prefill logits
         with its own knobs/seed — one sync per admission (prefill is
         per-request anyway); the advanced key parks in the slot row."""
-        key = self._request_key(req)
-        new_key, tok_arr = self._sample1(
-            logits[:, -1], key[None],
-            jnp.full((1,), req.temperature, jnp.float32),
-            jnp.full((1,), req.top_k, jnp.int32),
-            jnp.full((1,), req.top_p, jnp.float32))
-        tok = int(_host(tok_arr)[0])
-        self._keys = self._keys.at[slot].set(new_key[0])
-        self._temp[slot] = req.temperature
-        self._topk[slot] = req.top_k
-        self._topp[slot] = req.top_p
+        with obs.span("serve.first_token", rid=req.rid, slot=slot):
+            key = self._request_key(req)
+            new_key, tok_arr = self._sample1(
+                logits[:, -1], key[None],
+                jnp.full((1,), req.temperature, jnp.float32),
+                jnp.full((1,), req.top_k, jnp.int32),
+                jnp.full((1,), req.top_p, jnp.float32))
+            with obs.span("serve.sync", what="first_token"):
+                tok = int(_host(tok_arr)[0])
+            self._keys = self._keys.at[slot].set(new_key[0])
+            self._temp[slot] = req.temperature
+            self._topk[slot] = req.top_k
+            self._topp[slot] = req.top_p
         return tok
 
     def _install(self, req: Request, slot: int, cache1, n_prompt: int
@@ -663,45 +665,50 @@ class ServeEngine:
         retain any published prefix pages, allocate the rest (evicting
         cold prefixes under pressure), scatter the stripe's pages into
         the pool, write the slot's table row, and publish this prompt's
-        full pages for future sharing."""
-        if self._geom is None or not self._pages_active:
-            def insert(dst, src, ax):
-                return jax.lax.dynamic_update_slice_in_dim(
-                    dst, src.astype(dst.dtype), slot, axis=ax)
-            self.cache = jax.tree.map(insert, self.cache, cache1,
-                                      self._axes)
-            return
+        full pages for future sharing.  Prefix-cache hits count into
+        ``stats.prefix_hit_tokens`` once the allocation has succeeded,
+        so an :class:`OutOfPages` requeue counts nothing."""
+        with obs.span("serve.install", rid=req.rid, slot=slot):
+            if self._geom is None or not self._pages_active:
+                def insert(dst, src, ax):
+                    return jax.lax.dynamic_update_slice_in_dim(
+                        dst, src.astype(dst.dtype), slot, axis=ax)
+                self.cache = jax.tree.map(insert, self.cache, cache1,
+                                          self._axes)
+                return
 
-        geom = self._geom
-        n_reserve = min(n_prompt + req.max_new_tokens, self.max_len)
-        t_alloc = geom.pages_for(n_reserve)
-        share = (self.model.cfg.family in _SHARE_FAMILIES
-                 and req.frontend_embeds is None)
-        shared: list[int] = []
-        if share:
-            _, shared = self._prefix.lookup(req.prompt)
-            shared = shared[:t_alloc]
-            # hold the hits before allocating: eviction under pressure
-            # must not recycle the very pages this admission is reusing
-            for p in shared:
-                self._alloc.retain(p)
-        try:
-            own = self._alloc_pages(t_alloc - len(shared))
-        except OutOfPages:
-            self._alloc.release_all(shared)
-            raise
-        pages = shared + own
-        write_ids = np.full((geom.table_len,), TRASH_PAGE, np.int32)
-        table_ids = np.full((geom.table_len,), TRASH_PAGE, np.int32)
-        table_ids[:t_alloc] = pages
-        write_ids[len(shared):t_alloc] = pages[len(shared):]
-        self.cache = self._insert_paged(
-            self.cache, cache1, slot,
-            jnp.asarray(write_ids), jnp.asarray(table_ids))
-        self._slot_pages[slot] = pages
-        if share:
-            self._prefix.publish(req.prompt, pages)
-        self._page_gauges()
+            geom = self._geom
+            n_reserve = min(n_prompt + req.max_new_tokens, self.max_len)
+            t_alloc = geom.pages_for(n_reserve)
+            share = (self.model.cfg.family in _SHARE_FAMILIES
+                     and req.frontend_embeds is None)
+            shared: list[int] = []
+            if share:
+                _, shared = self._prefix.lookup(req.prompt)
+                shared = shared[:t_alloc]
+                # hold the hits before allocating: eviction under
+                # pressure must not recycle the very pages this
+                # admission is reusing
+                for p in shared:
+                    self._alloc.retain(p)
+            try:
+                own = self._alloc_pages(t_alloc - len(shared))
+            except OutOfPages:
+                self._alloc.release_all(shared)
+                raise
+            self.stats.prefix_hit_tokens += len(shared) * geom.page_size
+            pages = shared + own
+            write_ids = np.full((geom.table_len,), TRASH_PAGE, np.int32)
+            table_ids = np.full((geom.table_len,), TRASH_PAGE, np.int32)
+            table_ids[:t_alloc] = pages
+            write_ids[len(shared):t_alloc] = pages[len(shared):]
+            self.cache = self._insert_paged(
+                self.cache, cache1, slot,
+                jnp.asarray(write_ids), jnp.asarray(table_ids))
+            self._slot_pages[slot] = pages
+            if share:
+                self._prefix.publish(req.prompt, pages)
+            self._page_gauges()
 
     def _alloc_pages(self, n: int) -> list[int]:
         """Atomic n-page allocation, evicting LRU prefix entries on
@@ -821,123 +828,140 @@ class ServeEngine:
     def step(self) -> list[tuple[int, int]]:
         """Admissions + one fused decode block (``steps_per_dispatch``
         decode iterations, one host sync).  Returns streamed
-        (rid, token) events in emission order."""
-        events: list[tuple[int, int]] = []
+        (rid, token) events in emission order.
+
+        Traced, the step is one ``serve.step`` span whose children
+        cover its host phases: ``serve.prefill_chunk`` / ``serve.admit``
+        (with ``serve.install``, ``serve.first_token`` and its
+        ``serve.sync``), ``serve.dispatch`` (``serve.launch`` of the
+        block, then ``serve.sync`` of its tokens) and ``serve.emit``
+        (the drain, retirements included)."""
         self._step += 1
-        self._last_prefill_s = 0.0
-        self._last_dispatch_s = 0.0
+        with obs.span("serve.step", step=self._step,
+                      active=self.num_slots - self._slots.count(None),
+                      pending=len(self._pending),
+                      chunking=len(self._chunking)):
+            events: list[tuple[int, int]] = []
+            self._last_prefill_s = 0.0
+            self._last_dispatch_s = 0.0
 
-        # in-flight chunked admissions first: one chunk each per step,
-        # interleaved between decode dispatches, so a long prompt never
-        # head-of-line blocks the slots that are already decoding
-        for slot in sorted(self._chunking):
-            events.extend(self._advance_chunk(slot))
-
-        blocked = False
-        for slot in range(self.num_slots):
-            if (self._slots[slot] is not None or slot in self._chunking
-                    or not self._pending):
-                continue
-            req = self._pending.popleft()
-            t0 = _now()
-            queue_wait = t0 - self._submit_t.pop(req.rid, t0)
-            if self._chunkable(req):
-                self._start_chunking(req, slot, queue_wait, t0 - queue_wait)
+            # in-flight chunked admissions first: one chunk each per step,
+            # interleaved between decode dispatches, so a long prompt never
+            # head-of-line blocks the slots that are already decoding
+            for slot in sorted(self._chunking):
                 events.extend(self._advance_chunk(slot))
-                continue
-            try:
-                with obs.span("serve.admit", rid=req.rid, slot=slot,
-                              step=self._step, prompt_len=len(req.prompt)):
-                    tok = self._admit(req, slot)
-            except OutOfPages:
-                # pool exhausted: requeue at the front and stop
-                # admitting — active slots will retire and free pages
-                self._submit_t[req.rid] = t0 - queue_wait
-                self._pending.appendleft(req)
-                blocked = True
-                break
-            t1 = _now()
-            dt = t1 - t0
-            self.stats.prefill_s += dt
-            self.stats.prefill_tokens += len(req.prompt)
-            self.stats.admitted += 1
-            self._last_prefill_s = max(self._last_prefill_s, dt)
-            # TTFT: submit -> first token on the host (the prefill
-            # logits' sample); queue wait is the pre-admission share
-            ttft = queue_wait + dt
-            self.stats.queue_wait_s.append(queue_wait)
-            self.stats.ttft_s.append(ttft)
-            st = SlotState(request=req, tokens=[tok], next_token=tok,
-                           admitted_step=self._step,
-                           queue_wait_s=queue_wait, ttft_s=ttft)
-            self._slots[slot] = st
-            events.append((req.rid, tok))
-            if self._done(st, tok):
-                self._retire(slot)
 
-        active = [i for i, s in enumerate(self._slots) if s is not None]
-        if blocked and not active and not self._chunking:
-            raise OutOfPages(
-                f"page pool exhausted: request {self._pending[0].rid} "
-                f"cannot be admitted and no active request remains to "
-                f"free pages (pool: {self._geom.usable_pages} usable "
-                f"pages of {self._geom.page_size} tokens)")
-        self.stats.max_concurrent = max(self.stats.max_concurrent,
-                                        len(active))
-        if not active:
-            return events
+            blocked = False
+            for slot in range(self.num_slots):
+                if (self._slots[slot] is not None or slot in self._chunking
+                        or not self._pending):
+                    continue
+                req = self._pending.popleft()
+                t0 = _now()
+                queue_wait = t0 - self._submit_t.pop(req.rid, t0)
+                if self._chunkable(req):
+                    self._start_chunking(req, slot, queue_wait,
+                                         t0 - queue_wait)
+                    events.extend(self._advance_chunk(slot))
+                    continue
+                try:
+                    with obs.span("serve.admit", rid=req.rid, slot=slot,
+                                  step=self._step,
+                                  prompt_len=len(req.prompt)):
+                        tok = self._admit(req, slot)
+                except OutOfPages:
+                    # pool exhausted: requeue at the front and stop
+                    # admitting — active slots will retire and free pages
+                    self._submit_t[req.rid] = t0 - queue_wait
+                    self._pending.appendleft(req)
+                    blocked = True
+                    break
+                t1 = _now()
+                dt = t1 - t0
+                self.stats.prefill_s += dt
+                self.stats.prefill_tokens += len(req.prompt)
+                self.stats.admitted += 1
+                self._last_prefill_s = max(self._last_prefill_s, dt)
+                # TTFT: submit -> first token on the host (the prefill
+                # logits' sample); queue wait is the pre-admission share
+                ttft = queue_wait + dt
+                self.stats.queue_wait_s.append(queue_wait)
+                self.stats.ttft_s.append(ttft)
+                st = SlotState(request=req, tokens=[tok], next_token=tok,
+                               admitted_step=self._step,
+                               queue_wait_s=queue_wait, ttft_s=ttft)
+                self._slots[slot] = st
+                events.append((req.rid, tok))
+                if self._done(st, tok):
+                    self._retire(slot)
 
-        K = self.steps_per_dispatch
-        toks = np.zeros((self.num_slots,), np.int32)
-        done = np.ones((self.num_slots,), bool)
-        budget = np.zeros((self.num_slots,), np.int32)
-        for i in active:
-            st = self._slots[i]
-            toks[i] = st.next_token
-            done[i] = False
-            budget[i] = st.request.max_new_tokens - len(st.tokens)
+            active = [i for i, s in enumerate(self._slots) if s is not None]
+            if blocked and not active and not self._chunking:
+                raise OutOfPages(
+                    f"page pool exhausted: request {self._pending[0].rid} "
+                    f"cannot be admitted and no active request remains to "
+                    f"free pages (pool: {self._geom.usable_pages} usable "
+                    f"pages of {self._geom.page_size} tokens)")
+            self.stats.max_concurrent = max(self.stats.max_concurrent,
+                                            len(active))
+            if not active:
+                return events
 
-        # all-greedy pools (the default) take the argmax-specialized
-        # block — no sampler sorts/draws in the hot loop
-        fn = (self._decode_block_greedy
-              if all(self._temp[i] == 0.0 for i in active)
-              else self._decode_block)
-        t0 = _now()
-        with obs.span("serve.dispatch", step=self._step, k=K,
-                      active=len(active)):
-            self.cache, block, self._keys = fn(
-                self.params, self.cache, jnp.asarray(toks), self._keys,
-                jnp.asarray(self._temp), jnp.asarray(self._topk),
-                jnp.asarray(self._topp), jnp.asarray(done),
-                jnp.asarray(budget))
-            block = _host(block)     # THE one sync of this dispatch
-        dt = _now() - t0
-        self._last_dispatch_s = dt
-        self.stats.decode_s += dt
-        self.stats.decode_steps += K
-        self.stats.dispatches += 1
-        self.stats.dispatch_occupancy.append(len(active) / self.num_slots)
-        # a block's K iterations share one sync, so each token in it
-        # landed after dt/K of amortized decode latency — by design
-        # identical across K for a fixed per-iteration cost
-        per_token_s = dt / K
-
-        # drain the (num_slots, K) tile in step-major order so the
-        # event stream is ordered exactly like K single-step dispatches
-        for k in range(K):
+            K = self.steps_per_dispatch
+            toks = np.zeros((self.num_slots,), np.int32)
+            done = np.ones((self.num_slots,), bool)
+            budget = np.zeros((self.num_slots,), np.int32)
             for i in active:
                 st = self._slots[i]
-                if st is None:       # retired at an earlier k
-                    continue
-                tok = int(block[i, k])
-                st.tokens.append(tok)
-                st.next_token = tok
-                self.stats.decode_tokens += 1
-                self.stats.token_latency_s.append(per_token_s)
-                events.append((st.request.rid, tok))
-                if self._done(st, tok):
-                    self._retire(i)
-        return events
+                toks[i] = st.next_token
+                done[i] = False
+                budget[i] = st.request.max_new_tokens - len(st.tokens)
+
+            # all-greedy pools (the default) take the argmax-specialized
+            # block — no sampler sorts/draws in the hot loop
+            fn = (self._decode_block_greedy
+                  if all(self._temp[i] == 0.0 for i in active)
+                  else self._decode_block)
+            t0 = _now()
+            with obs.span("serve.dispatch", step=self._step, k=K,
+                          active=len(active)):
+                with obs.span("serve.launch"):
+                    self.cache, block, self._keys = fn(
+                        self.params, self.cache, jnp.asarray(toks),
+                        self._keys, jnp.asarray(self._temp),
+                        jnp.asarray(self._topk), jnp.asarray(self._topp),
+                        jnp.asarray(done), jnp.asarray(budget))
+                with obs.span("serve.sync", what="decode_block"):
+                    block = _host(block)  # THE one sync of this dispatch
+            dt = _now() - t0
+            self._last_dispatch_s = dt
+            self.stats.decode_s += dt
+            self.stats.decode_steps += K
+            self.stats.dispatches += 1
+            self.stats.dispatch_occupancy.append(len(active) / self.num_slots)
+
+            # drain the (num_slots, K) tile in step-major order so the
+            # event stream is ordered exactly like K single-step
+            # dispatches; the block's tokens share one emission time
+            with obs.span("serve.emit", step=self._step, active=len(active)):
+                t_emit = _now()
+                for k in range(K):
+                    for i in active:
+                        st = self._slots[i]
+                        if st is None:       # retired at an earlier k
+                            continue
+                        tok = int(block[i, k])
+                        st.tokens.append(tok)
+                        st.next_token = tok
+                        self.stats.decode_tokens += 1
+                        if st.last_emit_s is not None:
+                            self.stats.token_latency_s.append(
+                                t_emit - st.last_emit_s)
+                        st.last_emit_s = t_emit
+                        events.append((st.request.rid, tok))
+                        if self._done(st, tok):
+                            self._retire(i)
+            return events
 
     # ------------------------------------------------------------------
     def run(self, requests: Sequence[Request] = (), *,

@@ -78,3 +78,4 @@ class SlotState:
     admitted_step: int
     queue_wait_s: float = 0.0
     ttft_s: float = 0.0
+    last_emit_s: float | None = None   # engine clock, last decode emission

@@ -6,8 +6,9 @@ Two kinds of state live here:
 
 * **aggregates** — the original dict's nine counters (``prefill_s``,
   ``decode_tokens``, ...), now attributes with types;
-* **samples** — per-request TTFT and queue wait, per-token latency,
-  and per-dispatch occupancy, appended by the engine as it runs and
+* **samples** — per-request TTFT and queue wait, per-token latency
+  (the gap between a request's consecutive decode emissions), and
+  per-dispatch occupancy, appended by the engine as it runs and
   summarized on demand (:meth:`EngineStats.latency_summary`).
 
 ``snapshot()`` flattens everything into one JSON-safe dict — the shape
@@ -71,8 +72,15 @@ class EngineStats:
     pages_in_use: int = 0
     pages_shared: int = 0
     prefill_chunks: int = 0
+    # prompt tokens whose KV pages came from the prefix cache (counted
+    # once the admission's pages are allocated); over prefill_tokens it
+    # is the share of prefill a prefix skip would remove
+    prefix_hit_tokens: int = 0
 
-    # per-request / per-dispatch samples
+    # per-request / per-dispatch samples.  token_latency_s: the
+    # engine-clock gap between a request's consecutive decode
+    # emissions, one per decode token after its first (a K-block's
+    # tokens share one emission time: one gap, then K - 1 zeros)
     ttft_s: list[float] = field(default_factory=list)
     queue_wait_s: list[float] = field(default_factory=list)
     token_latency_s: list[float] = field(default_factory=list)
@@ -115,6 +123,7 @@ class EngineStats:
             out.pages_in_use += p.pages_in_use
             out.pages_shared += p.pages_shared
             out.prefill_chunks += p.prefill_chunks
+            out.prefix_hit_tokens += p.prefix_hit_tokens
             out.ttft_s.extend(p.ttft_s)
             out.queue_wait_s.extend(p.queue_wait_s)
             out.token_latency_s.extend(p.token_latency_s)
@@ -143,6 +152,7 @@ class EngineStats:
             "pages_in_use": self.pages_in_use,
             "pages_shared": self.pages_shared,
             "prefill_chunks": self.prefill_chunks,
+            "prefix_hit_tokens": self.prefix_hit_tokens,
         })
         out.update(self.latency_summary())
         return out

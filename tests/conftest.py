@@ -7,6 +7,8 @@ import sys
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+# the repo root too: tests read profiles with the benchmark's reader
+sys.path.insert(1, os.path.join(os.path.dirname(__file__), ".."))
 
 import numpy as np
 import pytest

@@ -41,6 +41,42 @@ def test_capture_records_spans_and_events():
     assert sink.records[1]["rid"] == 7
 
 
+def test_engine_spans_are_profiler_annotations(tmp_path):
+    """With tracing on, every span is also a profiler annotation: a CPU
+    trace of an engine run holds each ``serve.*`` span on the host
+    plane, as the benchmark's reader loads it, and each annotation
+    holds its sink record's interval."""
+    from chipbench import trace as tr
+    from repro.configs import get_config
+    from repro.models import Ctx, build_model
+    from repro.serve import Request, ServeEngine
+    model = build_model(get_config("gemma-7b", reduced=True))
+    params = model.init(jax.random.PRNGKey(0), dtype=jnp.float32)
+    eng = ServeEngine(model, params, Ctx(plan="jnp", dtype=jnp.float32),
+                      num_slots=2, max_len=32, page_size=4)
+
+    def requests(base):
+        return [Request(rid=base + i, prompt=[1, 2, 3, 4, 5, 6, 7 + i],
+                        max_new_tokens=3) for i in range(2)]
+    eng.run(requests(0))                 # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    with obs.capture() as sink:
+        eng.run(requests(10))
+    jax.profiler.stop_trace()
+    t = tr.load(str(tmp_path), host_prefixes=("serve.",))
+    spans = [r for r in sink.records if r["type"] == "span"]
+    assert sorted(h[0] for h in t.host) == sorted(s["name"] for s in spans)
+    assert {s["name"] for s in spans} == {
+        "serve.step", "serve.admit", "serve.install", "serve.first_token",
+        "serve.sync", "serve.dispatch", "serve.launch", "serve.emit"}
+    for name in {s["name"] for s in spans}:
+        notes = [h for h in t.host if h[0] == name]
+        recs = sorted((s for s in spans if s["name"] == name),
+                      key=lambda s: s["t"])
+        for (_, start, end), rec in zip(notes, recs):
+            assert end - start >= rec["dur_s"] * 1e9 - 1
+
+
 def test_jsonl_sink_roundtrip(tmp_path):
     path = os.path.join(tmp_path, "trace.jsonl")
     obs.enable(trace_path=path)

@@ -1,5 +1,6 @@
 """ServeEngine latency metrics: TTFT, queue wait, per-token
-percentiles, EngineStats typing/shim, split timeouts, obs spans.
+percentiles, EngineStats typing/shim, split timeouts, obs spans, the
+step's span tree and the prefix-hit counter.
 
 The latency tests monkeypatch the engine's module-level clock
 (``engine._now``) with a fake that only advances when the wrapped
@@ -7,6 +8,8 @@ prefill/decode callables run, each by a fixed synthetic cost — so
 every recorded latency is an exact, deterministic number and the
 K-invariance claims become equality assertions instead of tolerances.
 """
+
+import collections
 
 import jax
 import jax.numpy as jnp
@@ -16,8 +19,9 @@ import pytest
 from repro import obs
 from repro.configs import get_config
 from repro.models import Ctx, build_model
-from repro.serve import EngineStats, Request, ServeEngine
+from repro.serve import EngineStats, Request, Router, ServeEngine
 from repro.serve import engine as engine_mod
+from repro.serve.paging import OutOfPages
 from repro.serve.stats import _LEGACY_KEYS
 
 KEY = jax.random.PRNGKey(0)
@@ -105,10 +109,11 @@ def test_latency_metrics_deterministic_under_fake_clock(
 
 def test_ttft_and_token_p99_invariant_across_k(bundle, monkeypatch):
     """With every request admitted in step 1 (slots >= requests), TTFT
-    depends only on admission order and per-token latency is the
-    amortized block cost — both exactly equal for K=1 and K=4."""
+    depends only on admission order — exactly equal for K=1 and K=4 —
+    and per-token latency is each gap between a request's consecutive
+    decode emissions, which a K-block delivers together."""
     cfg, model, params = bundle
-    per_req, summaries = {}, {}
+    per_req, summaries, gaps = {}, {}, {}
     for k in (1, 4):
         clock = FakeClock()
         monkeypatch.setattr(engine_mod, "_now", clock)
@@ -121,16 +126,22 @@ def test_ttft_and_token_p99_invariant_across_k(bundle, monkeypatch):
         per_req[k] = {r.rid: (r.ttft_s, r.queue_wait_s)
                       for r in results.values()}
         summaries[k] = eng.stats.latency_summary()
+        gaps[k] = collections.Counter(eng.stats.token_latency_s)
     assert per_req[1] == per_req[4]
     assert summaries[1]["ttft"] == summaries[4]["ttft"]
     # i-th admission of the first step: i prior prefills in front of it
     assert per_req[1][0] == (PREFILL_C, 0.0)
     assert per_req[1][3] == (4 * PREFILL_C, 3 * PREFILL_C)
-    # every token's amortized latency is the per-iteration cost, so the
-    # whole distribution (p50 == p99 == max) is K-invariant
-    for k in (1, 4):
-        tok = summaries[k]["token_latency"]
-        assert tok["p50"] == tok["p99"] == tok["max"] == DECODE_C
+    # 5 + 2 + 4 + 6 decode tokens; a request's first decode token has
+    # no earlier decode emission, so 17 - 4 gaps.  K=1: one block per
+    # token, every gap DECODE_C.  K=4: a block's first token arrives
+    # 4 * DECODE_C after the previous block's tokens, the rest at 0
+    # (rids 0 and 3 take a second block)
+    assert gaps[1] == {DECODE_C: 13}
+    assert gaps[4] == {4 * DECODE_C: 2, 0.0: 11}
+    tok = summaries[1]["token_latency"]
+    assert tok["p50"] == tok["p99"] == tok["max"] == DECODE_C
+    assert summaries[4]["token_latency"]["max"] == 4 * DECODE_C
 
 
 def test_queue_wait_for_mid_run_admission(bundle, monkeypatch):
@@ -213,7 +224,8 @@ def test_stats_is_typed_with_derived_throughput(bundle):
     snap = s.snapshot()
     assert snap["admitted"] == 4 and snap["num_slots"] == 2
     assert snap["ttft"]["n"] == 4
-    assert snap["token_latency"]["n"] == s.decode_tokens
+    # one gap per decode token after each request's first decode token
+    assert snap["token_latency"]["n"] == s.decode_tokens - 4
     # engine.throughput() stays consistent with the typed stats
     assert eng.throughput()["decode_tok_s"] == s.decode_tok_s
 
@@ -257,3 +269,110 @@ def test_engine_emits_spans_and_retire_events(bundle):
     assert admit["type"] == "span" and admit["prompt_len"] == len(reqs[0].prompt)
     retire = next(r for r in sink.records if r["name"] == "serve.retire")
     assert retire["tokens"] == 3
+
+
+# ----------------------------------------------------------------------
+# the step's span tree and the prefix-hit counter (paged engine)
+# ----------------------------------------------------------------------
+PS = 4   # tokens per page
+
+
+def _shared_prefix_prompts(vocab):
+    """Three prompts over one 8-token prefix: two keep all of it, one
+    only its first 6 tokens."""
+    a, b, c = _prompts(vocab, (11, 12, 10))
+    prefix = a[:8]
+    return [a, prefix + b[:4], prefix[:6] + c[:4]]
+
+
+def _full_page_overlap(prompts, ps):
+    """Prompt tokens the prefix cache serves when the prompts are
+    installed in order: each one's longest full-page prefix shared with
+    an earlier prompt."""
+    def lcp(a, b):
+        n = 0
+        while n < min(len(a), len(b)) and a[n] == b[n]:
+            n += 1
+        return n
+    return sum(max((lcp(p, q) // ps * ps for q in prompts[:i]), default=0)
+               for i, p in enumerate(prompts))
+
+
+def _within(inner, outer):
+    return (outer["t"] <= inner["t"] and inner["t"] + inner["dur_s"]
+            <= outer["t"] + outer["dur_s"])
+
+
+@pytest.mark.parametrize("prefill_chunk,fail_once", [
+    (None, False), (8, False), (None, True), (8, True)])
+def test_step_span_tree_and_prefix_hits(bundle, monkeypatch, prefill_chunk,
+                                        fail_once):
+    """Every span lies inside a ``serve.step``; every ``serve.sync``
+    inside ``serve.dispatch`` or ``serve.first_token``, one per device
+    read; ``prefix_hit_tokens`` is the hand-computed full-page overlap,
+    also when the second install first fails with ``OutOfPages`` (a
+    requeued or parked admission counts once, when it lands)."""
+    cfg, model, params = bundle
+    reads = []
+    host = engine_mod._host
+
+    def counted(x):
+        reads.append(1)
+        return host(x)
+    monkeypatch.setattr(engine_mod, "_host", counted)
+    eng = ServeEngine(model, params, CTX, num_slots=4, max_len=32,
+                      page_size=PS, prefill_chunk=prefill_chunk,
+                      steps_per_dispatch=2)
+    if fail_once:
+        alloc, calls = eng._alloc_pages, []
+
+        def flaky(n):
+            calls.append(n)
+            if len(calls) == 2:
+                raise OutOfPages("one-shot pool pressure")
+            return alloc(n)
+        eng._alloc_pages = flaky
+    prompts = _shared_prefix_prompts(cfg.vocab_size)
+    with obs.capture() as sink:
+        results = eng.run([Request(rid=i, prompt=p, max_new_tokens=3)
+                           for i, p in enumerate(prompts)])
+    assert all(len(r.tokens) == 3 for r in results.values())
+    spans = [r for r in sink.records if r["type"] == "span"]
+    steps = [s for s in spans if s["name"] == "serve.step"]
+    assert [s["step"] for s in steps] == list(range(1, eng._step + 1))
+    assert all(any(_within(s, st) for st in steps) for s in spans)
+    syncs = [s for s in spans if s["name"] == "serve.sync"]
+    holders = [s for s in spans
+               if s["name"] in ("serve.dispatch", "serve.first_token")]
+    assert all(any(_within(s, h) for h in holders) for s in syncs)
+    assert len(syncs) == len(reads)
+    assert collections.Counter(s["what"] for s in syncs) == {
+        "first_token": 3, "decode_block": eng.stats.dispatches}
+    assert {s["name"] for s in spans} >= {
+        "serve.step", "serve.install", "serve.first_token", "serve.sync",
+        "serve.dispatch", "serve.launch", "serve.emit"}
+    for inner, outer in (("serve.launch", "serve.dispatch"),
+                         ("serve.install", "serve.step")):
+        assert all(any(_within(s, o) for o in spans if o["name"] == outer)
+                   for s in spans if s["name"] == inner)
+    hits = _full_page_overlap(prompts, PS)
+    assert hits == 12
+    assert eng.stats.prefix_hit_tokens == hits
+    assert eng.stats.snapshot()["prefix_hit_tokens"] == hits
+    assert eng.stats.prefill_tokens == sum(map(len, prompts))
+
+
+def test_merge_and_router_sum_prefix_hits(bundle):
+    cfg, model, params = bundle
+    parts = [EngineStats(prefix_hit_tokens=n) for n in (3, 0, 5)]
+    assert EngineStats.merge(parts).prefix_hit_tokens == 8
+    engines = [ServeEngine(model, params, CTX, num_slots=2, max_len=32,
+                           page_size=PS) for _ in range(2)]
+    router = Router(engines)
+    prompts = _shared_prefix_prompts(cfg.vocab_size)
+    router.run([Request(rid=i, prompt=p, max_new_tokens=2)
+                for i, p in enumerate(prompts + prompts[:1])])
+    per = [e.stats.prefix_hit_tokens for e in engines]
+    assert sum(per) > 0
+    assert router.stats().prefix_hit_tokens == sum(per)
+    assert router.snapshot()["prefix_hit_tokens"] == sum(per)
