@@ -14,8 +14,12 @@ clock at the window's start), and reports the largest gap between the
 two at either end.  It counts the spans per step, then times one span
 on this host with tracing off, on, and on under a running profiler,
 and gives the share of a step's wall time that a step's spans take when
-on.  Prints the run's notes on standard error, then one JSON object
-(the result line and the check) as the last line of standard output.
+on.  It adds how the stacked layer weights reached the matmul kernel
+in the run's programs (``repro.kernels.ops.layer_weight_counts``: call
+sites and one layer's weight bytes, read in place or sliced, and the
+in-place share of the bytes in %).  Prints the run's notes on standard
+error, then one JSON object (the result line, the check and the weight
+reads) as the last line of standard output.
 Needs the chip the cell asks for (exits 2 without it).
 """
 
@@ -97,6 +101,17 @@ def clock_check(profile_dir: str, run) -> dict:
                                  if steps else 0.0)}
 
 
+def weight_reads() -> dict:
+    """The counts of layer weights read in place or sliced in the
+    programs traced so far, and the in-place share of their bytes."""
+    from repro.kernels import ops
+    counts = ops.layer_weight_counts()
+    total = counts["in_place.bytes"] + counts["sliced.bytes"]
+    return {"weight_reads": counts,
+            "in_place_byte_share": (100.0 * counts["in_place.bytes"]
+                                    / total if total else None)}
+
+
 def check(workload: str, seed: int, seconds: float, *, log=print,
           **run_kw) -> dict:
     """The traced run, then the clock check on its profile and the span
@@ -125,7 +140,7 @@ def check(workload: str, seed: int, seconds: float, *, log=print,
     got["tracing_share_of_step"] = (got["spans_per_step"] * extra
                                     / got["step_wall_s_mean"]
                                     if got["step_wall_s_mean"] else None)
-    return {"result": out, "check": got}
+    return {"result": out, "check": got, **weight_reads()}
 
 
 def main(argv=None) -> int:

@@ -136,4 +136,8 @@ RULES = {
     "ZS-K005": ("error", "kernel-ir",
                 "input_output_aliases never overwrite an input window "
                 "a later grid step still reads"),
+    "ZS-K006": ("error", "kernel-ir",
+                "every manual DMA reads a window inside its source "
+                "operand (a row offset into a stacked weight stays "
+                "inside the stack)"),
 }

@@ -40,6 +40,13 @@ Rules (catalog in ``analyze.RULES`` / docs/ARCHITECTURE.md):
   (the accumulation run is split — broken HBM streaming).
 * ``ZS-K005`` — ``input_output_aliases`` overlap a live input window
   (an aliased output write lands on a block a later step still reads).
+* ``ZS-K006`` — a manual DMA reads a window outside its source operand
+  (a row offset into a stacked weight runs past the stack).
+
+Small operands the body reads as values (the matmul's row offset into
+B) are taken from the traced program where they are constants
+(``KernelIR.operand_values``); a DMA whose source depends on any other
+value escapes the interpreter (``ZS-K001``).
 """
 
 from __future__ import annotations
@@ -98,6 +105,7 @@ class KernelIR:
     input_output_aliases: tuple
     dimension_semantics: tuple | None
     contract: ScheduleContract | None
+    operand_values: dict = dataclasses.field(default_factory=dict)
 
     @property
     def total_steps(self) -> int:
@@ -121,26 +129,69 @@ def find_pallas_eqns(jaxpr) -> list:
     """All ``pallas_call`` equations in ``jaxpr``, recursively
     (entry points wrap the kernel call in ``pjit``/``custom_jvp``
     layers — the search descends through every sub-jaxpr param)."""
+    return [eqn for eqn, _ in _pallas_calls(jaxpr)]
+
+
+#: shape/dtype-only primitives a constant operand is folded through
+_FOLD_PRIMS = ("convert_element_type", "reshape", "broadcast_in_dim",
+               "squeeze")
+#: largest operand (elements) whose constant value is kept
+_FOLD_CAP = 64
+#: primitives whose sub-jaxpr takes the equation's operands as invars
+_CALL_PRIMS = ("pjit", "jit", "closed_call", "core_call",
+               "custom_jvp_call", "custom_vjp_call")
+
+
+def _pallas_calls(jaxpr) -> list:
+    """``[(eqn, {operand position: value})]`` for every ``pallas_call``
+    in ``jaxpr``: the value of each small operand that is a constant of
+    the traced program (a literal folded through reshapes, casts and
+    broadcasts, across ``pjit`` boundaries)."""
+    import jax
+
     found = []
     seen = set()
 
-    def walk(jx):
+    def walk(jx, known):
         if id(jx) in seen:
             return
         seen.add(id(jx))
+
+        def value(atom):
+            if isinstance(atom, jax.extend.core.Literal):
+                return np.asarray(atom.val)
+            return known.get(atom)
+
         for eqn in jx.eqns:
-            if eqn.primitive.name == "pallas_call":
-                found.append(eqn)
+            ins = [value(v) for v in eqn.invars]
+            name = eqn.primitive.name
+            if name == "pallas_call":
+                found.append((eqn, {i: v for i, v in enumerate(ins)
+                                    if v is not None}))
+            elif name in _FOLD_PRIMS and ins and ins[0] is not None:
+                out = eqn.outvars[0].aval
+                if math.prod(out.shape) <= _FOLD_CAP:
+                    v = ins[0].astype(out.dtype)
+                    known[eqn.outvars[0]] = (
+                        np.full(out.shape, v.item(), out.dtype)
+                        if v.size == 1 else v.reshape(out.shape))
             for val in eqn.params.values():
                 vals = val if isinstance(val, (tuple, list)) else (val,)
                 for v in vals:
                     sub = getattr(v, "jaxpr", None)
-                    if sub is not None and hasattr(sub, "eqns"):
-                        walk(sub)
-                    elif hasattr(v, "eqns"):
-                        walk(v)
+                    sub = sub if sub is not None and hasattr(
+                        sub, "eqns") else (v if hasattr(v, "eqns")
+                                           else None)
+                    if sub is None:
+                        continue
+                    # a call passes its operands straight to its invars
+                    passed = ({iv: x for iv, x in zip(sub.invars, ins)
+                               if x is not None}
+                              if name in _CALL_PRIMS
+                              and len(sub.invars) == len(ins) else {})
+                    walk(sub, passed)
 
-    walk(_strip_closed(jaxpr))
+    walk(_strip_closed(jaxpr), {})
     return found
 
 
@@ -150,8 +201,9 @@ def _strip_closed(jx):
     return jx
 
 
-def extract_kernel_ir(eqn) -> KernelIR:
-    """Read one ``pallas_call`` equation into a :class:`KernelIR`."""
+def extract_kernel_ir(eqn, operand_values=None) -> KernelIR:
+    """Read one ``pallas_call`` equation into a :class:`KernelIR`;
+    ``operand_values`` maps operand positions to known values."""
     gm = eqn.params["grid_mapping"]
     blocks = []
     for i, bmap in enumerate(gm.block_mappings):
@@ -183,15 +235,17 @@ def extract_kernel_ir(eqn) -> KernelIR:
         input_output_aliases=tuple(eqn.params.get(
             "input_output_aliases") or ()),
         dimension_semantics=tuple(sem) if sem is not None else None,
-        contract=contract_for(name))
+        contract=contract_for(name),
+        operand_values=dict(operand_values or {}))
 
 
 def trace_kernel_irs(fn: Callable, *args, **kwargs) -> list:
-    """``jax.make_jaxpr`` an entry point and extract every kernel IR."""
+    """``jax.make_jaxpr`` an entry point and extract every kernel IR
+    (with the values of its constant operands)."""
     import jax
 
     jx = jax.make_jaxpr(lambda *a: fn(*a, **kwargs))(*args)
-    return [extract_kernel_ir(e) for e in find_pallas_eqns(jx)]
+    return [extract_kernel_ir(e, vals) for e, vals in _pallas_calls(jx)]
 
 
 # ----------------------------------------------------------------------
@@ -362,6 +416,15 @@ class _Interp:
                 return [self._get(eqn, invals)]
             return [_OPAQUE] * n_out
 
+        if prim == "multiple_of":
+            # an alignment promise to the compiler: hold the kernel to it
+            v = invals[0]
+            if _is_scalar(v) and any(v % m for m in eqn.params["values"]):
+                raise _Uninterpretable(
+                    f"index {v} breaks its multiple_of"
+                    f"{tuple(eqn.params['values'])} hint")
+            return [v]
+
         if prim == "convert_element_type":
             v = invals[0]
             if isinstance(v, (bool, np.bool_)):
@@ -471,9 +534,13 @@ def _split_dma(invals) -> list:
     return groups
 
 
-def _replay_body(ir: KernelIR, steps: int) -> list:
+def _replay_body(ir: KernelIR, steps: int, on_dma=None) -> list:
     """Interpret the body once per grid step, recording DMA/read
-    events.  Returns a list of :class:`_StepTrace`."""
+    events.  Returns a list of :class:`_StepTrace`.  The operands in
+    ``ir.operand_values`` are readable refs;
+    ``on_dma(step, src_ref, src_starts, dst_ref, dst_starts)`` sees
+    every ``dma_start``."""
+    values = ir.operand_values
     traces = []
     for t, ids in enumerate(_grid_points(ir, steps)):
         tr = _StepTrace(step=t)
@@ -491,6 +558,9 @@ def _replay_body(ir: KernelIR, steps: int) -> list:
                         f"{prim} index not concrete at step {t}")
                 slot = int(dst_idx[0]) if dst_idx else 0
                 if prim == "dma_start":
+                    if on_dma is not None:
+                        on_dma(t, src, [int(v) for v in src_idx], dst,
+                               [int(v) for v in dst_idx])
                     tr.starts.append(_Start(
                         step=t, ref=dst.index, slot=slot, src=src.index,
                         src_key=tuple(int(v) for v in src_idx),
@@ -513,7 +583,8 @@ def _replay_body(ir: KernelIR, steps: int) -> list:
 
         interp = _Interp(program_ids=ids, grid=ir.grid,
                          on_event=on_event)
-        args = [_RefVal(i) for i in range(len(ir.jaxpr.invars))]
+        args = [_RefVal(i, values.get(i))
+                for i in range(len(ir.jaxpr.invars))]
         interp.run(ir.jaxpr, ir.consts, args)
         traces.append(tr)
     return traces
@@ -548,15 +619,37 @@ def _analyze_managed(ir: KernelIR, report: Report, where: str,
                      max_steps: int) -> None:
     total = ir.total_steps
     steps = min(total, max_steps)
+    outside = []
+
+    def on_dma(t, src, starts, dst, dst_starts):
+        # the window is the destination ref less its indexed dims
+        shape = tuple(ir.jaxpr.invars[src.index].aval.shape)
+        window = tuple(ir.jaxpr.invars[dst.index].aval.shape
+                       )[len(dst_starts):]
+        if len(starts) == len(shape) == len(window) and any(
+                s < 0 or s + w > n
+                for s, w, n in zip(starts, window, shape)):
+            outside.append((t, src.index, tuple(starts), window, shape))
+
     try:
-        traces = _replay_body(ir, steps)
+        traces = _replay_body(ir, steps, on_dma)
     except _Uninterpretable as e:
         report.add(Diagnostic(
             rule="ZS-K001", severity="error", where=where,
             message=f"kernel body escaped the IR interpreter: {e}",
             hint="keep slot/DMA indexing a pure function of "
-                 "program_id"))
+                 "program_id and of operands whose values are "
+                 "constants of the traced program"))
         return
+    if outside:
+        t, src, starts, window, shape = outside[0]
+        report.add(Diagnostic(
+            rule="ZS-K006", severity="error", where=where,
+            message=f"step {t} DMAs the {window} window at {starts} "
+                    f"of operand {src}, outside its {shape} extent "
+                    f"({len(outside)} such DMA(s))",
+            hint="a row offset into a stacked operand must keep every "
+                 "tile inside the stack"))
     _resolve_consumers(traces)
 
     dst_refs, depths = _slot_depth(ir, traces)
@@ -865,7 +958,8 @@ def lint_kernel_ir(ir: KernelIR, *, where: str | None = None,
                    max_steps: int = 96) -> Report:
     """Verify one extracted kernel IR.  ``scalar_args`` supplies
     concrete values for scalar-prefetch operands (page tables, length
-    vectors) so data-dependent index maps are evaluable."""
+    vectors) so data-dependent index maps are evaluable; operands the
+    body reads as values come from ``ir.operand_values``."""
     report = Report()
     where = where or ir.name
     scalar_args = tuple(scalar_args)
@@ -931,9 +1025,13 @@ def lint_kernels(families=None, *, space=None, backend: str = "interpret",
         prob = Problem("matmul", 32, 32, 32, dtype_bytes=4)
         a = jnp.ones((32, 32), jnp.float32)
         b = jnp.ones((32, 32), jnp.float32)
+        stack = jnp.ones((3, 32, 32), jnp.float32)
         for cand in space.candidates(prob):
             run("zero_stall", trace_kernel_irs(
                 ops.matmul, a, b, config=matmul_cfg(cand)))
+            # the last layer of a stack, read in place
+            run("zero_stall", trace_kernel_irs(
+                ops.matmul, a, stack, layer=2, config=matmul_cfg(cand)))
 
     if "grouped" in picks:
         prob = Problem("grouped_matmul", 16, 16, 16, dtype_bytes=4,

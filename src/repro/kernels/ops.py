@@ -64,14 +64,17 @@ from repro.plan import UNSET as _UNSET, KernelConfig, Plan
 from repro.quant.tensor import QTensor, quantize_rows
 
 __all__ = ["matmul", "grouped_matmul", "attention", "paged_attention",
-           "host_tiled_matmul", "quantized_matmul",
+           "host_tiled_matmul", "quantized_matmul", "layer_weight_counts",
            "quantized_grouped_matmul", "resolve_impl",
            "reset_fallback_warnings", "fallback_counts", "FallbackError",
            "strict_fallbacks"]
 
 
+_WEIGHT_PREFIX = "matmul.weight_"
+
+
 def _record(op: str, *, M, N, K, dtype, backend, config=None, groups=1,
-            batch_heads=1) -> None:
+            batch_heads=1, in_place: bool | None = None) -> None:
     """Report this dispatch to the observability layer (when on).
 
     These wrappers execute at **trace time** under ``jax.jit``, so a
@@ -79,7 +82,17 @@ def _record(op: str, *, M, N, K, dtype, backend, config=None, groups=1,
     signature — exactly the kernel set of the compiled program, which
     is what the utilization table prices (see
     :mod:`repro.obs.kernel_watch`).  Off by default: one boolean check.
+
+    ``in_place`` marks a matmul whose weight is one layer of a stack
+    (``matmul(..., layer=)``): whether the kernel read it in place or
+    it was sliced out first.  That is counted always, by call site and
+    by the layer's weight bytes (:func:`layer_weight_counts`).
     """
+    if in_place is not None:
+        kind = _WEIGHT_PREFIX + ("in_place" if in_place else "sliced")
+        _obs.counter_inc(kind + ".calls")
+        _obs.counter_inc(kind + ".bytes",
+                         int(K) * int(N) * np.dtype(dtype).itemsize)
     if _obs.enabled():
         _obs.record_dispatch(op, M=M, N=N, K=K, dtype=dtype,
                              backend=backend, config=config, groups=groups,
@@ -174,9 +187,10 @@ def _legacy_config(op: str, config, legacy: dict):
     return KernelConfig(backend=impl, **used)
 
 
-def matmul(a: jax.Array, b: jax.Array, *, config=None, out_dtype=None,
-           impl=_UNSET, bm=_UNSET, bn=_UNSET, bk=_UNSET, variant=_UNSET,
-           slots=_UNSET, grid_order=_UNSET, tiling=_UNSET) -> jax.Array:
+def matmul(a: jax.Array, b: jax.Array, *, layer=None, config=None,
+           out_dtype=None, impl=_UNSET, bm=_UNSET, bn=_UNSET, bk=_UNSET,
+           variant=_UNSET, slots=_UNSET, grid_order=_UNSET,
+           tiling=_UNSET) -> jax.Array:
     """C = A @ B through the zero-stall engine.
 
     The workhorse entry point: every linear layer in the model zoo
@@ -186,27 +200,73 @@ def matmul(a: jax.Array, b: jax.Array, *, config=None, out_dtype=None,
     pre-plan spelling.  Arbitrary shapes are zero-padded to tile
     multiples and sliced back — padding contributes zeros to the
     contraction, so results are exact and independent of the config.
+
+    With ``layer`` (a scalar, traced or not), ``b`` is a stacked
+    (L, K, N) weight and the product is ``a @ b[layer]``.  Where the
+    kernel runs and its tiles divide K and N, the stack is viewed as
+    (L*K, N) — a bitcast — and the kernel DMAs the layer's tiles
+    straight out of it at row ``layer * K``: no copy of the layer is
+    made.  Otherwise (the jnp backend, or tiles that would pad the
+    weight) ``b[layer]`` is sliced out and takes the ordinary path.
+    Both give the same bits: either reads ``layer`` as
+    ``lax.dynamic_index_in_dim`` does (a negative index counts from
+    the end, then it is clamped into the stack).
     """
     config = _legacy_config("matmul", config, {
         "impl": impl, "bm": bm, "bn": bn, "bk": bk, "variant": variant,
         "slots": slots, "grid_order": grid_order, "tiling": tiling})
     backend = resolve_impl(_plan.config_backend(config, "matmul"))
-    M, N, K = a.shape[0], b.shape[1], a.shape[1]
+    M, N, K = a.shape[0], b.shape[-1], a.shape[1]
     if out_dtype is None:
         out_dtype = _config_out_dtype(config, _plan.OpKey(
             "matmul", M, N, K, dtype=_plan.dtype_name(a.dtype)))
     if backend == "jnp":
-        _record("matmul", M=M, N=N, K=K, dtype=a.dtype, backend=backend)
+        if layer is not None:
+            b = jax.lax.dynamic_index_in_dim(b, layer, keepdims=False)
+        _record("matmul", M=M, N=N, K=K, dtype=a.dtype, backend=backend,
+                in_place=None if layer is None else False)
         return _ref.matmul_ref(a, b, out_dtype)
     cfg = _plan.resolve(config, op="matmul", M=M, N=N, K=K,
                         dtype=a.dtype, backend=backend)
+    in_place = None
+    row0 = 0
+    if layer is not None:
+        in_place = K % cfg.bk == 0 and N % cfg.bn == 0
+        if in_place:
+            row0 = _stack_index(layer, b.shape[0]) * K
+            b = b.reshape(-1, N)
+        else:
+            b = jax.lax.dynamic_index_in_dim(b, layer, keepdims=False)
     _record("matmul", M=M, N=N, K=K, dtype=a.dtype, backend=backend,
-            config=cfg)
+            config=cfg, in_place=in_place)
     ap = _pad_to(a, (cfg.bm, cfg.bk))
     bp = _pad_to(b, (cfg.bk, cfg.bn))
-    c = zero_stall_matmul(ap, bp, interpret=(backend == "interpret"),
+    c = zero_stall_matmul(ap, bp, row0,
+                          interpret=(backend == "interpret"),
                           out_dtype=out_dtype, **cfg.matmul_kwargs())
     return c[:M, :N]
+
+
+def _stack_index(layer, L: int):
+    """``layer`` as ``lax.dynamic_index_in_dim`` reads it from an
+    L-deep stack: a negative index counts from the end, then the index
+    is clamped into 0..L-1."""
+    if isinstance(layer, int):
+        return min(max(layer + L if layer < 0 else layer, 0), L - 1)
+    layer = jnp.asarray(layer, jnp.int32)
+    return jnp.clip(jnp.where(layer < 0, layer + L, layer), 0, L - 1)
+
+
+def layer_weight_counts() -> dict[str, int]:
+    """How the stacked layer weights reached the matmul kernel since
+    ``obs.reset_counters("matmul.weight_")``: ``{"in_place.calls",
+    "in_place.bytes", "sliced.calls", "sliced.bytes"}``, each a traced
+    call site (once per compiled program) and the bytes of the one
+    layer's weight it reads."""
+    pre = _WEIGHT_PREFIX
+    got = {k[len(pre):]: v for k, v in _obs.counters(pre).items()}
+    return {k: got.get(k, 0) for k in ("in_place.calls", "in_place.bytes",
+                                       "sliced.calls", "sliced.bytes")}
 
 
 def grouped_matmul(a: jax.Array, b: jax.Array, *, config=None,

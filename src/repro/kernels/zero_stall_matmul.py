@@ -68,11 +68,13 @@ _META = register_family("zero_stall_matmul", grid_rank=3,
                         managed_dma=True, sequential_axes="all")
 
 
-def _kernel(a_hbm, b_hbm, c_ref, a_vmem, b_vmem, acc, sem_a, sem_b, *,
-            bm: int, bn: int, bk: int, slots: int, out_dtype,
+def _kernel(a_hbm, b_hbm, row0_ref, c_ref, a_vmem, b_vmem, acc, sem_a,
+            sem_b, *, bm: int, bn: int, bk: int, slots: int, out_dtype,
             grid_shape: tuple[int, int, int], order: str):
-    """Kernel body; a_vmem/b_vmem have a leading `slots` dimension."""
+    """Kernel body; a_vmem/b_vmem have a leading `slots` dimension.
+    B's contraction rows start at row ``row0_ref[0]`` of ``b_hbm``."""
     p0, p1, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    row0 = row0_ref[0]
     g0, g1, gk = grid_shape          # static (wrapper-provided)
     total = g0 * g1 * gk
     i, j = (p0, p1) if order == "ijk" else (p1, p0)
@@ -91,7 +93,8 @@ def _kernel(a_hbm, b_hbm, c_ref, a_vmem, b_vmem, acc, sem_a, sem_b, *,
             a_hbm.at[pl.ds(ii * bm, bm), pl.ds(kk * bk, bk)],
             a_vmem.at[slot], sem_a.at[slot])
         cp_b = pltpu.make_async_copy(
-            b_hbm.at[pl.ds(kk * bk, bk), pl.ds(jj * bn, bn)],
+            b_hbm.at[pl.ds(pl.multiple_of(row0 + kk * bk, bk), bk),
+                     pl.ds(jj * bn, bn)],
             b_vmem.at[slot], sem_b.at[slot])
         return cp_a, cp_b
 
@@ -159,6 +162,7 @@ def _kernel(a_hbm, b_hbm, c_ref, a_vmem, b_vmem, acc, sem_a, sem_b, *,
 def zero_stall_matmul(
     a: jax.Array,
     b: jax.Array,
+    row0: jax.Array | int = 0,
     *,
     bm: int = DEFAULT_TILES[0],
     bn: int = DEFAULT_TILES[1],
@@ -171,19 +175,29 @@ def zero_stall_matmul(
 ) -> jax.Array:
     """C = A @ B with explicit zero-stall tiling.
 
-    A: (M, K), B: (K, N); M, N, K must be multiples of the tile sizes
-    (``ops.matmul`` pads arbitrary shapes before calling this).
+    A: (M, K), B: (R, N) with R a multiple of K; the contraction reads
+    B's rows ``row0 .. row0 + K``.  An ordinary product passes B as
+    (K, N) and ``row0 = 0``; a stacked (L, K, N) weight reshaped to
+    (L*K, N) with ``row0 = layer * K`` is read in place, one layer's
+    tiles DMA'd straight out of the stack.  ``row0`` may be traced (it
+    rides into the kernel as an int32 SMEM operand after A and B) and
+    must be a multiple of ``bk``.  M, N, K must be multiples of the
+    tile sizes (``ops.matmul`` pads arbitrary shapes before calling
+    this).
 
     ``slots`` sets the revolving-buffer depth (None → 2 for "dobu",
     1 for "single"); ``grid_order`` picks which output dimension the
     outermost grid loop walks ("ijk" = rows outer, "jik" = cols outer —
     k stays fastest in both, as the accumulator requires).
     """
-    (M, K), (K2, N) = a.shape, b.shape
-    if K != K2:
+    (M, K), (R, N) = a.shape, b.shape
+    if R < K or R % K:
         raise ValueError(f"contraction mismatch: {a.shape} @ {b.shape}")
     if M % bm or N % bn or K % bk:
         raise ValueError(f"shapes {(M, K, N)} not multiples of tiles {(bm, bk, bn)}")
+    if isinstance(row0, int) and (row0 % bk or not 0 <= row0 <= R - K):
+        raise ValueError(f"row offset {row0} is no multiple of bk={bk} "
+                         f"inside B's {R} rows")
     if grid_order not in ("ijk", "jik"):
         raise ValueError(f"grid_order must be 'ijk' or 'jik', got {grid_order!r}")
     out_dtype = out_dtype or a.dtype
@@ -203,6 +217,7 @@ def zero_stall_matmul(
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),   # A stays in HBM
             pl.BlockSpec(memory_space=pl.ANY),   # B stays in HBM
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # B's first row
         ],
         out_specs=pl.BlockSpec((bm, bn), out_map),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
@@ -218,4 +233,4 @@ def zero_stall_matmul(
         interpret=interpret,
         name=kernel_name("zero_stall_matmul", slots=slots,
                          grid_order=grid_order),
-    )(a, b)
+    )(a, b, jnp.asarray(row0, jnp.int32).reshape(1))

@@ -203,6 +203,47 @@ def _matmul(x2: jax.Array, w: jax.Array, ctx: Ctx, *, out_dtype,
                          out_specs=specs[2], check_vma=False)(x2, w)
 
 
+@dataclasses.dataclass(frozen=True)
+class StackedWeight:
+    """Layer ``layer`` of a stacked (L, K, N) linear weight, left in the
+    stack.  :func:`linear` hands both to ``ops.matmul(..., layer=)``,
+    which lets the kernel read the layer's tiles in place instead of
+    copying the layer out of the stack first."""
+
+    stack: jax.Array
+    layer: jax.Array
+
+    @property
+    def shape(self) -> tuple:
+        return tuple(self.stack.shape[1:])
+
+
+def layer_params(stacked: Params, layer: jax.Array, ctx: Ctx, *,
+                 sliced: tuple[str, ...] = ()) -> Params:
+    """Layer ``layer`` of the scan-stacked parameter tree.
+
+    Every linear weight (``{"w": (L, K, N)}``) that is a plain array in
+    the compute dtype, with no mesh to split it, stays in its stack as
+    a :class:`StackedWeight`.  Everything else — norm scales, biases,
+    quantized weights, the top-level subtrees named in ``sliced`` — is
+    sliced at ``layer``, as ``lax.scan`` slices its ``xs``."""
+    def take(x):
+        return jax.lax.dynamic_index_in_dim(x, layer, keepdims=False)
+
+    def walk(node):
+        if not isinstance(node, dict):
+            return jax.tree.map(take, node)
+        return {k: (StackedWeight(v, layer) if k == "w" and keep(v)
+                    else walk(v)) for k, v in node.items()}
+
+    def keep(w):
+        return (isinstance(w, jax.Array) and w.ndim == 3
+                and w.dtype == ctx.dtype and not _kernels_on_mesh(ctx))
+
+    return {k: jax.tree.map(take, v) if k in sliced else walk(v)
+            for k, v in stacked.items()}
+
+
 def linear(p: Params, x: jax.Array, ctx: Ctx, *,
            split: str | None = None) -> jax.Array:
     """x: (..., d_in) @ w -> (..., d_out) through the zero-stall engine.
@@ -214,12 +255,18 @@ def linear(p: Params, x: jax.Array, ctx: Ctx, *,
     and runs the standard kernel — so fp8-simulated and opted-out
     quantized params still execute on the Pallas path, never a jnp
     fallback.  ``split`` ("col" | "row") is this layer's place in
-    Megatron tensor parallelism (see :func:`_matmul`).
+    Megatron tensor parallelism (see :func:`_matmul`).  A
+    :class:`StackedWeight` (from :func:`layer_params`) goes to the
+    kernel with its stack and layer index.
     """
     w = p["w"]
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
-    if isinstance(w, QTensor):
+    if isinstance(w, StackedWeight):
+        y = ops.matmul(x2, w.stack, layer=w.layer, config=ctx.plan,
+                       out_dtype=ctx.dtype)
+        d_out = w.shape[-1]
+    elif isinstance(w, QTensor):
         if ctx.plan.quant == "int8" and w.fmt == "int8" and w.w8a8:
             y = ops.quantized_matmul(x2, w, config=ctx.plan,
                                      out_dtype=ctx.dtype)

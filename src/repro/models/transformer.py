@@ -4,6 +4,18 @@ Shared by the dense / vlm / moe families (moe swaps the MLP).  Layers
 are stacked along a leading axis and executed with `jax.lax.scan`, so
 HLO size and compile time are O(1) in depth — required to lower the
 88-layer mistral-large-123b in this container (DESIGN.md §7.3).
+
+How a layer's weights reach the kernels: training (`forward`,
+`loss_fn`) scans over the stacked parameters as its ``xs``, which
+slices every weight out of its stack each iteration.  The serving
+scans (`prefill`, `prefill_chunk`, `decode_step`) scan over the layer
+index instead, with the stacks closed over: each linear weight stays
+in its (L, K, N) stack and ``ops.matmul(..., layer=i)`` lets the
+zero-stall kernel DMA layer i's tiles straight out of it, where the
+kernel runs and its tiles divide the weight.  Otherwise — norm scales,
+quantized weights, MoE experts, a mesh, the jnp backend, tiles that
+would pad the weight — the layer is sliced as the ``xs`` scan slices
+it, with the same result.
 """
 
 from __future__ import annotations
@@ -176,6 +188,19 @@ def _layer_decode(cfg: ModelConfig, ctx: Ctx, mlp_fn: Callable | None,
     return x + y, new_cache
 
 
+def _layer_ids(layers: Params) -> jax.Array:
+    """0..L-1, the serving scans' ``xs`` in place of the weights."""
+    return jnp.arange(jax.tree.leaves(layers)[0].shape[0], dtype=jnp.int32)
+
+
+def _layer_at(layers: Params, ctx: Ctx, mlp_fn: Callable | None):
+    """Layer i's parameters, the weight stacks closed over rather than
+    scanned (:func:`repro.models.layers.layer_params`).  A custom
+    ``mlp_fn`` (MoE experts) gets its subtree sliced."""
+    sliced = ("mlp",) if mlp_fn is not None else ()
+    return lambda i: L.layer_params(layers, i, ctx, sliced=sliced)
+
+
 def decode_step(params: Params, cache: Params, tokens: jax.Array,
                 cfg: ModelConfig, ctx: Ctx,
                 *, mlp_fn: Callable | None = None
@@ -190,16 +215,18 @@ def decode_step(params: Params, cache: Params, tokens: jax.Array,
     pos = cache["pos"]
     page_table = cache.get("page_table")
     x = L.embed(params["embed"], tokens, ctx)
+    layer_at = _layer_at(params["layers"], ctx, mlp_fn)
 
     def scan_body(x, layer):
-        lp, lc = layer
-        x, new_lc = _layer_decode(cfg, ctx, mlp_fn, x, lp, lc, pos,
-                                  page_table)
+        i, lc = layer
+        x, new_lc = _layer_decode(cfg, ctx, mlp_fn, x, layer_at(i), lc,
+                                  pos, page_table)
         return x, new_lc
 
     lc = {k: v for k, v in cache.items()
           if k not in ("pos", "page_table")}
-    x, new_kv = jax.lax.scan(scan_body, x, (params["layers"], lc))
+    x, new_kv = jax.lax.scan(scan_body, x,
+                             (_layer_ids(params["layers"]), lc))
     x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
     logits = L.unembed(params["embed"], x, ctx)
     out = {**new_kv, "pos": pos + 1}
@@ -237,8 +264,10 @@ def prefill(params: Params, tokens: jax.Array, cfg: ModelConfig, ctx: Ctx,
     hd = cfg.resolved_head_dim
     lens = None if lengths is None else (
         jnp.asarray(lengths, jnp.int32) + n_front)
+    layer_at = _layer_at(params["layers"], ctx, mlp_fn)
 
-    def scan_body(x, lp):
+    def scan_body(x, i):
+        lp = layer_at(i)
         h = L.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
         q, k, v = L._qkv(lp["attn"], h, cfg, ctx)
         q = L.rope(q, positions, cfg.rope_theta)
@@ -255,7 +284,7 @@ def prefill(params: Params, tokens: jax.Array, cfg: ModelConfig, ctx: Ctx,
         x = x + y
         return x, {"k": k, "v": v}
 
-    x, kv = jax.lax.scan(scan_body, x, params["layers"])
+    x, kv = jax.lax.scan(scan_body, x, _layer_ids(params["layers"]))
     x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
     if lens is None:
         x_last = x[:, -1:]
@@ -308,9 +337,11 @@ def prefill_chunk(params: Params, tokens: jax.Array, cfg: ModelConfig,
     positions = off_b[:, None] + jnp.arange(C)[None, :]
     hd = cfg.resolved_head_dim
     zero = jnp.zeros((), jnp.int32)
+    layer_at = _layer_at(params["layers"], ctx, mlp_fn)
 
     def scan_body(x, layer):
-        lp, lc = layer
+        i, lc = layer
+        lp = layer_at(i)
         h = L.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
         q, k, v = L._qkv(lp["attn"], h, cfg, ctx)
         q = L.rope(q, positions, cfg.rope_theta)
@@ -332,7 +363,8 @@ def prefill_chunk(params: Params, tokens: jax.Array, cfg: ModelConfig,
         return x + y, {"k": ck, "v": cv}
 
     lc = {k: v for k, v in cache.items() if k != "pos"}
-    x, new_kv = jax.lax.scan(scan_body, x, (params["layers"], lc))
+    x, new_kv = jax.lax.scan(scan_body, x,
+                             (_layer_ids(params["layers"]), lc))
     x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
     x_last = L.gather_last(x, lens - off_b)
     logits = L.unembed(params["embed"], x_last, ctx)

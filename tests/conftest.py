@@ -35,8 +35,11 @@ def _reset_fallback_warnings():
 def _reset_obs():
     """repro.obs holds process-global tracer state and kernel dispatch
     records; leave both clean after every test (tests that enable
-    tracing use obs.capture() or enable/disable themselves)."""
-    yield
+    tracing use obs.capture() or enable/disable themselves), and zero
+    the always-on counts of how layer weights reached the kernel."""
     from repro import obs
+    obs.reset_counters("matmul.weight_")
+    yield
     obs.reset_records()
     obs.disable()
+    obs.reset_counters("matmul.weight_")

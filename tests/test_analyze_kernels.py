@@ -9,15 +9,20 @@ rejected with *stable* rule ids:
   * a single-slot kernel issuing next-step prefetch *before* compute
     (overlap claimed with one buffer) -> ZS-K002 (in-flight WAR);
   * ``input_output_aliases`` writing a window a later grid step still
-    reads -> ZS-K005.
+    reads -> ZS-K005;
+  * a row offset that sends the matmul's B tiles past its layer stack
+    -> ZS-K006.
 
 The clean sweep here runs a trimmed space (one tile option) so tier-1
 stays fast; CI's ``scripts/analyze.py --kernels`` gate runs the full
 INTERPRET_SPACE sweep.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -54,7 +59,8 @@ def test_trace_kernel_irs_extracts_grid_blocks_and_contract():
 
 
 def test_kernel_rules_registered():
-    for rule in ("ZS-K001", "ZS-K002", "ZS-K003", "ZS-K004", "ZS-K005"):
+    for rule in ("ZS-K001", "ZS-K002", "ZS-K003", "ZS-K004", "ZS-K005",
+                 "ZS-K006"):
         severity, layer, _ = RULES[rule]
         assert severity == "error"
         assert layer == "kernel-ir"
@@ -233,3 +239,43 @@ def test_alias_disjoint_windows_is_clean():
         name="alias_in_place")
     report = lint_kernel_ir(ir)
     assert "ZS-K005" not in report.rules(), report.format()
+
+
+# ----------------------------------------------------------------------
+# mutation D: the matmul's row offset runs B's tiles past its stack
+# ----------------------------------------------------------------------
+_STACK_CFG = KernelConfig(backend="interpret", bm=8, bn=8, bk=8,
+                          variant="dobu", slots=2)
+
+
+def test_row_offset_past_the_stack_flags_zs_k006():
+    a = jnp.ones((32, 32), jnp.float32)
+    stack = jnp.ones((3, 32, 32), jnp.float32)
+    (ir,) = trace_kernel_irs(ops.matmul, a, stack, layer=2,
+                             config=_STACK_CFG)
+    # the offset of the last layer, folded from the traced program
+    assert ir.operand_values[2].tolist() == [64]
+    assert not lint_kernel_ir(ir).errors
+    report = lint_kernel_ir(dataclasses.replace(
+        ir, operand_values={2: np.array([96], np.int32)}))
+    assert "ZS-K006" in report.rules(), report.format()
+    # an offset off the tile grid breaks the kernel's alignment promise
+    report = lint_kernel_ir(dataclasses.replace(
+        ir, operand_values={2: np.array([36], np.int32)}))
+    assert any("multiple_of" in d.message for d in report.errors
+               if d.rule == "ZS-K001"), report.format()
+
+
+def test_traced_row_offset_needs_its_value():
+    """A layer index traced by the caller leaves the offset unknown:
+    the replay refuses to guess it, and checks it once supplied."""
+    a = jnp.ones((32, 32), jnp.float32)
+    stack = jnp.ones((3, 32, 32), jnp.float32)
+    (ir,) = trace_kernel_irs(
+        lambda a, s, i: ops.matmul(a, s, layer=i, config=_STACK_CFG),
+        a, stack, jnp.int32(1))
+    assert 2 not in ir.operand_values
+    assert "ZS-K001" in lint_kernel_ir(ir).rules()
+    ok = lint_kernel_ir(dataclasses.replace(
+        ir, operand_values={2: np.array([32], np.int32)}))
+    assert not ok.errors, ok.format()

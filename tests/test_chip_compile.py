@@ -15,14 +15,17 @@ process at a time may load the TPU library, and every test worker
 imports this file.
 """
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro import tune
+from repro import obs, tune
+from repro.configs import get_config
 from repro.kernels import ops
 from repro.kernels.grouped_matmul import grouped_zero_stall_matmul
 from repro.kernels.quantized_matmul import (
@@ -30,6 +33,7 @@ from repro.kernels.quantized_matmul import (
     quantized_zero_stall_matmul,
 )
 from repro.kernels.zero_stall_matmul import zero_stall_matmul
+from repro.models import Ctx, build_model
 from repro.plan import KernelConfig
 from repro.quant import QTensor
 from repro.tune import DEFAULT_SPACE, Problem
@@ -193,3 +197,68 @@ def test_largest_admitted_attention_tile_compiles(sds):
     cfg = KernelConfig(backend="pallas", bq=bq, bkv=bkv)
     _compile(lambda q, k, v: ops.attention(q, k, v, config=cfg),
              qkv, qkv, qkv)
+
+
+def _with_operand_shapes(hlo: str) -> str:
+    """The optimized HLO with each operand written as ``shape %name``,
+    the form in which the TPU profiler names an event."""
+    shapes = dict(re.findall(r"^\s*(?:ROOT )?(%\S+) = (\S+) ", hlo, re.M))
+    return re.sub(r"(?<=[(,] )(%[\w.\-]+)|(?<=\()(%[\w.\-]+)",
+                  lambda m: f"{shapes.get(m.group(0), '')} {m.group(0)}",
+                  hlo)
+
+
+def test_decode_step_reads_layer_weights_in_place(sds, fresh_tune_cache):
+    """A 2-layer deepseek-width decode step: every weight the tuner's
+    tiles divide reaches its ``zero_stall_matmul`` as a bitcast of the
+    (L*K, N) stack, never through a copy; the two that ``bn`` = 512
+    would pad (d_ff 19200) keep the slice.  The benchmark's roofline
+    reader attributes each in-place call to the problem recorded."""
+    from chipbench.trace import matmul_call, op_name, operand_shapes
+    cfg = dataclasses.replace(get_config("deepseek-coder-33b"), n_layers=2)
+    model = build_model(cfg)
+    ctx = Ctx(plan="pallas", dtype=jnp.bfloat16)
+
+    def described(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+    params = described(jax.eval_shape(
+        lambda k: model.init(k, dtype=jnp.bfloat16), jax.random.PRNGKey(0)))
+    cache = described(jax.eval_shape(
+        lambda: model.init_cache(8, 256, jnp.bfloat16)))
+    with obs.capture():
+        exe = _compile(lambda p, c, t: model.decode(p, c, t, ctx), params,
+                       cache, sds((8, 1), jnp.int32))
+        records = [(r.M, r.N, r.K, r.config.bm, r.config.bn, r.config.bk)
+                   for r in obs.recorded_ops() if r.op == "matmul"]
+    hlo = _with_operand_shapes(exe.as_text())
+    lines = [ln.strip() for ln in hlo.splitlines() if " = " in ln]
+    defs = {ln.split(" = ")[0].removeprefix("ROOT "): ln for ln in lines}
+
+    in_place = []
+    for ln in lines:
+        if not op_name(ln).startswith("zero_stall_matmul"):
+            continue
+        _, ((_, (_, k_pad)), (_, (rows, _)), *_) = operand_shapes(ln)
+        if rows == k_pad:
+            continue                      # a 2-D weight or a slice
+        call = matmul_call(ln, records)
+        assert (call.M, call.N, call.K) in {r[:3] for r in records}
+        assert rows == 2 * call.K
+        in_place.append((call.N, call.K))
+        src = re.search(r"custom-call\([^%]*%\S+, [^%]*(%[\w.\-]+)",
+                        ln).group(1)
+        while " bitcast(" in defs[src]:
+            src = re.search(r"bitcast\([^%]*(%[\w.\-]+)", defs[src]).group(1)
+        assert re.search(r" (get-tuple-element|parameter)\(", defs[src]), \
+            defs[src]
+    assert sorted(in_place) == sorted([(7168, 7168), (1024, 7168),
+                                       (1024, 7168), (7168, 7168),
+                                       (7168, 19200)])
+    copies = {(K, N): [ln for ln in lines
+                       if f"bf16[2,{K},{N}]" in ln.split(" = ", 1)[1]
+                       and re.search(r" (fusion|copy|dynamic-slice)\(", ln)]
+              for K, N in [(7168, 7168), (7168, 1024), (19200, 7168),
+                           (7168, 19200)]}
+    assert not any(copies[k] for k in [(7168, 7168), (7168, 1024),
+                                       (19200, 7168)]), copies
+    assert copies[(7168, 19200)]          # wi and wg are still sliced

@@ -12,8 +12,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.configs import get_config, list_configs
 from repro.models import Ctx, build_model
+from repro.plan import KernelConfig
 
 CTX = Ctx(plan="jnp", dtype=jnp.float32)
 KEY = jax.random.PRNGKey(0)
@@ -170,3 +172,147 @@ def test_quantized_kv_decode():
     rel = np.max(np.abs(lf - lq)) / (np.max(np.abs(lf)) + 1e-9)
     assert rel < 0.05
     assert (lf.argmax(-1) == lq.argmax(-1)).all()
+
+
+# ----------------------------------------------------------------------
+# serving scans: layer weights read in place from the stacked params
+# ----------------------------------------------------------------------
+_SERVE_TILES = {
+    "divide": KernelConfig(backend="interpret", bm=8, bn=8, bk=8),
+    # N = 16 of wk/wv is no multiple of bn = 32: those two are sliced
+    "pad_kv": KernelConfig(backend="interpret", bm=8, bn=32, bk=8),
+}
+
+
+def _scan_weights_as_xs(monkeypatch):
+    """The serving scans as they were before in-place reads: the weight
+    stacks are the scan's ``xs``, sliced every iteration."""
+    from repro.models import transformer
+    monkeypatch.setattr(transformer, "_layer_ids", lambda layers: layers)
+    monkeypatch.setattr(transformer, "_layer_at",
+                        lambda layers, ctx, mlp_fn: lambda lp: lp)
+
+
+def _serve_step(model, params, ctx, step):
+    """(logits, cache) of one serving entry point on a 2-row batch."""
+    cfg = model.cfg
+    toks = jax.random.randint(jax.random.PRNGKey(5), (B, 8), 0,
+                              cfg.vocab_size)
+    if step == "decode_step":
+        _, cache = model.prefill(params, {"tokens": toks}, CTX, 16)
+        obs.reset_counters("matmul.weight_")     # count the decode only
+        return model.decode(params, cache, toks[:, :1], ctx)
+    if step == "prefill":
+        return model.prefill(params, {"tokens": toks,
+                                      "lengths": jnp.array([8, 5])},
+                             ctx, 16)
+    cache = model.init_cache(B, 16, jnp.float32)
+    return model.prefill_chunk(params, toks, ctx, cache=cache,
+                               offset=jnp.int32(0),
+                               lengths=jnp.array([8, 6], jnp.int32))
+
+
+@pytest.mark.parametrize("arch,step,tiles", [
+    ("deepseek-coder-33b", "decode_step", "divide"),
+    ("deepseek-coder-33b", "decode_step", "pad_kv"),
+    ("deepseek-coder-33b", "prefill_chunk", "divide"),
+    ("deepseek-coder-33b", "prefill_chunk", "pad_kv"),
+    ("deepseek-coder-33b", "prefill", "divide"),
+    ("granite-moe-1b-a400m", "decode_step", "divide"),
+    ("granite-moe-1b-a400m", "prefill", "divide"),
+])
+def test_serving_scans_read_layer_weights_in_place(monkeypatch, arch, step,
+                                                   tiles):
+    """In-place reads give the bits of the scan that slices every
+    weight; only linear weights the tiles divide go in place (dense
+    MLP yes, MoE experts no)."""
+    from repro.kernels import ops
+    cfg = get_config(arch, reduced=True)
+    model = build_model(cfg)
+    params = model.init(KEY, dtype=jnp.float32)
+    ctx = Ctx(plan=_SERVE_TILES[tiles], dtype=jnp.float32)
+    got = _serve_step(model, params, ctx, step)
+    counts = ops.layer_weight_counts()
+    with monkeypatch.context() as m:
+        _scan_weights_as_xs(m)
+        want = _serve_step(model, params, ctx, step)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    attn = 4 if tiles == "divide" else 2
+    mlp = 3 if cfg.family == "dense" else 0
+    assert counts["in_place.calls"] == attn + mlp
+    assert counts["sliced.calls"] == 4 - attn
+
+
+def _pallas_weight_sources(fn, *args):
+    """For each ``pallas_call`` in ``fn``'s jaxpr, how its second
+    operand was made: the primitives from an argument of ``fn`` (its
+    pytree path) to the operand, with ``xs`` for a scan's slicing."""
+    flat, _ = jax.tree_util.tree_flatten_with_path(args)
+    closed = jax.make_jaxpr(fn)(*args)
+    found = []
+
+    def walk(jx, env):
+        def src(v):
+            return env.get(v, ("const",)) if hasattr(v, "aval") and \
+                not isinstance(v, jax.extend.core.Literal) else ("const",)
+        for eqn in jx.eqns:
+            ins = [src(v) for v in eqn.invars]
+            name = eqn.primitive.name
+            if name == "pallas_call":
+                found.append(ins[1])
+            subs = [v for v in eqn.params.values() if hasattr(v, "jaxpr")]
+            for sub in subs if name != "pallas_call" else ():
+                sj = sub.jaxpr
+                if name == "scan":
+                    first_xs = (eqn.params["num_consts"]
+                                + eqn.params["num_carry"])
+                    sub_env = {iv: (("xs",) + s if k >= first_xs else s)
+                               for k, (iv, s) in enumerate(
+                                   zip(sj.invars, ins))}
+                else:
+                    sub_env = dict(zip(sj.invars, ins))
+                out_env = walk(sj, sub_env)
+                if name != "scan":
+                    for ov, sv in zip(eqn.outvars, sj.outvars):
+                        env[ov] = out_env.get(sv, ("const",))
+            for ov in eqn.outvars:
+                env.setdefault(ov, (name,) + ins[0] if ins else (name,))
+        return env
+
+    env = {iv: ("arg", jax.tree_util.keystr(path))
+           for iv, (path, _) in zip(closed.jaxpr.invars, flat)}
+    walk(closed.jaxpr, env)
+    return found
+
+
+def _from_weight_stack(source):
+    """The source chain ends at a layer weight stack ``...["w"]``."""
+    return source[-2] == "arg" and "['layers']" in source[-1] \
+        and source[-1].endswith("['w']")
+
+
+def test_decode_step_kernels_read_weight_stacks_unsliced(monkeypatch):
+    """No matmul kernel of ``decode_step`` takes a weight that was
+    sliced out of its stack; the old scan (weights as ``xs``) fails
+    the same check."""
+    cfg = get_config("deepseek-coder-33b", reduced=True)
+    model = build_model(cfg)
+    params = model.init(KEY, dtype=jnp.float32)
+    cache = model.init_cache(B, 16, jnp.float32)
+    ctx = Ctx(plan=_SERVE_TILES["divide"], dtype=jnp.float32)
+    toks = jnp.zeros((B, 1), jnp.int32)
+
+    def sources():
+        srcs = _pallas_weight_sources(
+            lambda p, c, t: model.decode(p, c, t, ctx), params, cache, toks)
+        return [s for s in srcs if _from_weight_stack(s)]
+
+    stacked = sources()
+    assert len(stacked) == 7          # wq wk wv wo wi wg wo
+    for s in stacked:
+        assert not {"dynamic_slice", "xs", "gather", "slice"} & set(s), s
+    with monkeypatch.context() as m:
+        _scan_weights_as_xs(m)
+        sliced = sources()
+    assert len(sliced) == 7 and all("xs" in s for s in sliced)
